@@ -4,8 +4,9 @@ Per (arch x shape x mesh): three terms in seconds from the compiled HLO
 (loop-aware analysis), dominant bottleneck, MODEL_FLOPS/HLO_FLOPS
 usefulness, and roofline fraction = ideal-compute-time / dominant-term.
 
-Hardware constants (TPU v5e-class, per chip):
-  197 TFLOP/s bf16 | 819 GB/s HBM | ~50 GB/s/link ICI
+Hardware peaks come from `PEAKS`, keyed by the `device_kind` JAX reports
+and recorded in each cell; a device that is not in the table is an error,
+never a default.
 """
 from __future__ import annotations
 
@@ -14,9 +15,23 @@ import json
 import os
 from pathlib import Path
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+# Per-chip peaks keyed by `jax.devices()[0].device_kind`. Source: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s inter-chip interconnect over 4 links = 50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The `PEAKS` row for `device_kind`; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak table for device_kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}"
+                         ) from None
+
 
 RESULTS = Path(__file__).resolve().parent.parent / "dryrun_results"
 
@@ -36,17 +51,18 @@ def load_cells(pattern="*.json", base_only=True):
 
 
 def terms(cell) -> dict:
+    pk = peaks(cell.get("device_kind"))
     la = cell.get("loop_aware", {})
     flops = la.get("flops_per_chip", 0.0)
     hbm = la.get("hbm_bytes_per_chip", 0.0)
     wire = la.get("wire_bytes_per_chip", 0.0)
-    t_c = flops / PEAK_FLOPS
-    t_m = hbm / HBM_BW
-    t_n = wire / ICI_BW
+    t_c = flops / pk["bf16_flops"]
+    t_m = hbm / pk["hbm_bw"]
+    t_n = wire / pk["ici_bw"]
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_n),
               key=lambda kv: kv[1])
     model_t = (cell.get("model_flops", 0.0) / cell.get("chips", 1)
-               / PEAK_FLOPS)
+               / pk["bf16_flops"])
     return {
         "arch": cell["arch"], "shape": cell["shape"], "mesh": cell["mesh"],
         "tag": cell.get("opt_overrides") or {},

@@ -50,6 +50,7 @@ from benchmarks import (capacity, figures, robustness, roofline, scaling,
                         serving)
 from benchmarks.common import ORDER
 from benchmarks.validate import assert_bench_schema, check
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCH_SERVE_JSON = Path("BENCH_serve.json")
 BENCH_ROBUST_JSON = Path("BENCH_robust.json")
@@ -72,6 +73,7 @@ def main() -> None:
                          "host devices (sets XLA_FLAGS before jax init; "
                          "sharded-vs-vmap columns in BENCH_scale.json)")
     args = ap.parse_args()
+    enable_compile_cache()
     r = 20000 if args.quick else None
     only = set(filter(None, args.only.split(",")))
 

@@ -1,9 +1,11 @@
 """Backend-dispatched wrappers for the Pallas kernels.
 
-On TPU the compiled Pallas kernels run natively; on CPU (this container)
-the pure-jnp oracles run instead, with interpret mode reserved for kernel
-validation in tests — never for production graphs (interpret is a Python
-interpreter, ~1000x slower).
+`impl` is "auto" | "pallas" | "ref". "auto" runs the compiled Pallas
+kernel on a TPU and the pure-jnp oracle (`kernels.ref`) anywhere else;
+"pallas" demands the kernel and raises off a TPU — it never falls back
+to interpret mode, which stays reserved for kernel validation in tests
+(they pass `interpret=True` to the kernel modules directly). Which
+implementation a call resolves to is `resolve_impl(impl)`.
 """
 from __future__ import annotations
 
@@ -17,40 +19,49 @@ from repro.kernels import ref as _ref
 from repro.kernels import residency_fused as _rf
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_impl(impl: str = "auto") -> str:
+    """"pallas" or "ref": what `impl` runs on the default backend.
+    Raises for "pallas" off a TPU (no silent interpret fallback)."""
+    on_tpu = jax.default_backend() == "tpu"
+    if impl == "pallas" and not on_tpu:
+        raise RuntimeError(
+            f"impl='pallas' needs a TPU backend, found "
+            f"{jax.default_backend()!r}; use 'ref' or 'auto' (tests call "
+            "the kernel modules with interpret=True)")
+    if impl not in ("auto", "pallas", "ref"):
+        raise ValueError(f"impl must be auto|pallas|ref, got {impl!r}")
+    return "pallas" if impl == "pallas" or on_tpu and impl == "auto" \
+        else "ref"
 
 
 def quantize_block_int8(x2d, impl: str = "auto"):
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
-        return _qdq.quantize_block_int8(x2d, interpret=not _on_tpu())
+    if resolve_impl(impl) == "pallas":
+        return _qdq.quantize_block_int8(x2d)
     return _ref.quantize_block_int8(x2d)
 
 
 def dequantize_block_int8(q, scale, out_dtype=jnp.float32,
                           impl: str = "auto"):
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
-        return _qdq.dequantize_block_int8(q, scale, out_dtype=out_dtype,
-                                          interpret=not _on_tpu())
+    if resolve_impl(impl) == "pallas":
+        return _qdq.dequantize_block_int8(q, scale, out_dtype=out_dtype)
     return _ref.dequantize_block_int8(q, scale, out_dtype)
 
 
 def bdi_compress(x2d_i32, impl: str = "auto"):
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
-        return _bdi.bdi_compress(x2d_i32, interpret=not _on_tpu())
+    if resolve_impl(impl) == "pallas":
+        return _bdi.bdi_compress(x2d_i32)
     return _ref.bdi_compress(x2d_i32)
 
 
 def bdi_decompress(base, deltas, ok, raw, impl: str = "auto"):
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
-        return _bdi.bdi_decompress(base, deltas, ok, raw,
-                                   interpret=not _on_tpu())
+    if resolve_impl(impl) == "pallas":
+        return _bdi.bdi_decompress(base, deltas, ok, raw)
     return _ref.bdi_decompress(base, deltas, ok, raw)
 
 
 def paged_gather(pool, idx, impl: str = "auto"):
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
-        return _pg.paged_gather(pool, idx, interpret=not _on_tpu())
+    if resolve_impl(impl) == "pallas":
+        return _pg.paged_gather(pool, idx)
     return _ref.paged_gather(pool, idx)
 
 
@@ -69,13 +80,11 @@ def residency_fused(res, kpool, vpool, remote_k, remote_v, landed,
     """The fused per-step residency transaction (landing + victim
     selection + writeback enqueue + pool scatter + CAM probe + hit
     gather + policy touch) — see ref.fused_residency_step for the
-    contract. impl: "auto" | "pallas" | "ref"; interpret mode is
-    reserved for kernel validation (tests), never production graphs."""
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
+    contract."""
+    if resolve_impl(impl) == "pallas":
         return _rf.fused_residency_step(
             res, kpool, vpool, remote_k, remote_v, landed, landed_pages,
-            needed_pages, needed_writes, clock, pol,
-            interpret=not _on_tpu())
+            needed_pages, needed_writes, clock, pol)
     return _ref.fused_residency_step(
         res, kpool, vpool, remote_k, remote_v, landed, landed_pages,
         needed_pages, needed_writes, clock, pol)

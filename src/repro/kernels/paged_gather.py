@@ -13,12 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gather_kernel(idx_ref, pool_ref, out_ref):

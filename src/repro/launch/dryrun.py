@@ -40,6 +40,9 @@ from repro.runtime.mesh_rules import use_mesh
 from repro.runtime.train_loop import TrainConfig, make_train_step
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "dryrun_results"
+# the chip the pod meshes stand for; benchmarks/roofline.py keys its peak
+# table by this (the compile itself runs on forced host devices)
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1,
                 "f8e5m2": 1, "s64": 8, "u64": 8, "s32": 4, "u32": 4,
@@ -137,6 +140,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     mesh_name = "multipod_2x16x16" if multi_pod else "pod_16x16"
     rec = {"arch": arch, "shape": shape_name, "mesh": mesh_name,
            "kind": shape.kind, "status": "started",
+           "device_kind": TARGET_DEVICE_KIND,
            "opt_overrides": opt_overrides or {},
            "dp_compress": dp_compress}
     ok, reason = cfg.shape_supported(shape)

@@ -16,6 +16,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def build_mesh(shape, axes, devices=None):
@@ -23,7 +24,12 @@ def build_mesh(shape, axes, devices=None):
     to `jax.devices()`), with a readable error when the host has fewer —
     the ONE validation path every mesh constructor below routes through.
     Works on real hardware and under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` alike."""
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` alike.
+
+    Every axis is `AxisType.Auto`: the installed JAX otherwise makes
+    explicit-sharding meshes, under which plain slices and reshapes of
+    sharded arrays (the lattice unpad, the MoE token regroup) raise
+    `ShardingTypeError`."""
     if len(shape) != len(axes):
         raise ValueError(f"mesh shape {shape} and axes {axes} disagree")
     n = math.prod(shape)
@@ -34,7 +40,8 @@ def build_mesh(shape, axes, devices=None):
             f"{len(devices)}; on CPU set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n} before "
             "importing jax")
-    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices[:n])
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _factor_2d(n: int):

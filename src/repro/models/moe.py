@@ -23,19 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map_fn
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-except ImportError:  # older spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
-
 from repro.models.layers import F32, ParamBuilder, dot, silu
 from repro.runtime.mesh_rules import active_mesh, dp_axis_names
 
@@ -204,8 +191,8 @@ def moe_ep(params, cfg, x, axis_name: str = "model"):
 
     body = partial(_ep_local, axis_name, cfg.num_experts,
                    cfg.experts_per_token, cfg.moe_capacity_factor)
-    yf = shard_map(
-        body, mesh,
+    yf = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(tspec, None), P(tspec, None), P(tspec, None),
                   P(axis_name, None, None), P(axis_name, None, None),
                   P(axis_name, None, None)),

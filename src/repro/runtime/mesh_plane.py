@@ -37,7 +37,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import fabric
@@ -87,13 +86,13 @@ def _sharded_lattice_jit(cfg, n_pages, telcfg, mesh, tflags, warm_after,
                                                  cus, pol)
         return jax.vmap(one_cell)(nets_loc, pols_loc)   # (cells_loc, S, C)
 
-    # check_rep=False: the replication checker mis-tracks scan carries
+    # check_vma=False: the replication checker mis-tracks scan carries
     # (jax#21427-style); every output is P("data")-sharded anyway so no
     # replication claim is being made
-    return shard_map(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(), P(), P(), P("data"), P(), P(), P("data")),
-        out_specs=P("data"), check_rep=False)(
+        out_specs=P("data"), check_vma=False)(
         tflags, warm_after, trace_arrays, nets_cells, comp_ratio,
         active_cus, pols_cells)
 
@@ -198,12 +197,12 @@ def _sharded_store_jit(cfg, mesh, active, state, remote_k, remote_v,
         st = st._replace(fab=fabric.reduce_deltas(base, st.fab, "data"))
         return st, k, v, hit
 
-    return shard_map(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh,
         in_specs=(_STATE_SPECS, P(), P(), P("data"), P("data"),
                   P("data")),
         out_specs=(_STATE_SPECS, P("data"), P("data"), P("data")),
-        check_rep=False)(
+        check_vma=False)(
         state, remote_k, remote_v, needed_pages, needed_offsets,
         needed_writes)
 

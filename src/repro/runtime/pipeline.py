@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.moe import shard_map  # same import shim
-
 
 def pipeline_forward(mesh, stage_fn, stage_params, x_micro,
                      axis: str = "stage"):
@@ -75,7 +73,7 @@ def pipeline_forward(mesh, stage_fn, stage_params, x_micro,
             jnp.where(stage_id == s - 1, buf, jnp.zeros_like(buf)), axis)
         return out
 
-    fn = shard_map(per_stage, mesh,
-                   in_specs=(P(axis), P()),
-                   out_specs=P())
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(P(axis), P()),
+                       out_specs=P())
     return fn(stage_params, x_micro)

@@ -16,6 +16,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
+from repro.launch.mesh import build_mesh
 from repro.models import moe as mo
 from repro.models.model import ModelOptions, init_model, loss_fn
 from repro.runtime.mesh_rules import use_mesh
@@ -27,8 +28,7 @@ from repro.configs.base import SMOKE_SHAPES
 
 def check_moe_ep_matches_dense():
     cfg = get_config("olmoe-1b-7b").reduced()          # 8 experts top-2
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         devices=jax.devices()[:8])
+    mesh = build_mesh((2, 4), ("data", "model"))
     p, _ = mo.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model)) * 0.3
     y_dense, aux_d = mo.moe_dense(p, cfg, x)
@@ -57,8 +57,7 @@ def check_moe_ep_matches_dense():
 
 def check_compressed_pod_sync():
     cfg = get_config("qwen3-1.7b").reduced()
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                         devices=jax.devices()[:8])
+    mesh = build_mesh((2, 2, 2), ("pod", "data", "model"))
     params, _ = init_model(jax.random.PRNGKey(0), cfg)
     batch = synthetic_batch(cfg, SMOKE_SHAPES["smoke_train"],
                             DataConfig(), 0)
@@ -84,7 +83,7 @@ def check_compressed_pod_sync():
 
 def check_pipeline_forward():
     from repro.runtime.pipeline import pipeline_forward
-    mesh = jax.make_mesh((4,), ("stage",), devices=jax.devices()[:4])
+    mesh = build_mesh((4,), ("stage",))
     s, m, mb, d = 4, 6, 2, 16
     keys = jax.random.split(jax.random.PRNGKey(0), s)
     w = jax.vmap(lambda k: jax.random.normal(k, (d, d)) * 0.3)(keys)
@@ -108,8 +107,7 @@ def check_sharded_train_step():
         shardings_for
     from repro.configs.base import ShapeConfig
     cfg = get_config("qwen3-1.7b").reduced()
-    mesh = jax.make_mesh((2, 2), ("data", "model"),
-                         devices=jax.devices()[:4])
+    mesh = build_mesh((2, 2), ("data", "model"))
     shape = ShapeConfig("tiny_train", 32, 4, "train")
     opt = model_options_for(cfg, shape, remat="none")
     args, axes = input_specs(cfg, shape, opt)

@@ -162,6 +162,17 @@ def test_store_fused_matches_chain(pol_name, ways):
         np.asarray(a), np.asarray(b)), o_ref, o_ch)
 
 
+def test_pallas_impl_raises_off_tpu():
+    """`kernel_impl="pallas"` demands the compiled kernel: off a TPU it
+    raises instead of falling back to interpret mode, and "auto" picks
+    the oracle there."""
+    from repro.kernels import ops
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        _drive(_mini_cfg("pallas", 2), steps=1)
+    assert ops.resolve_impl("auto") == "ref"
+
+
 def test_store_kernel_impl_single_compile():
     """The fused path keeps the store's single-compile property: one jit
     trace per (shape, kernel_impl) serves every step and every policy —
