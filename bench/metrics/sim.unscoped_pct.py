@@ -1,0 +1,8 @@
+"""Share of device busy time in the simulator's traced window under no
+`desim.*` scope, in %: ops outside the named phases, or named alike by
+two live programs."""
+from scopes import unscoped_pct
+
+
+def read(tr, ctx):
+    return unscoped_pct(tr, "desim.")

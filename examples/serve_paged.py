@@ -83,9 +83,10 @@ def tenant_capacity_demo(steps: int = 120):
                         policy="lru",  # swap for any residency.POLICIES
                         fabric=FabricConfig(num_modules=2),
                         # full telemetry plane: per-tenant stall
-                        # histograms + series ring + host spans
+                        # histograms + series ring (host spans come
+                        # from the recorder below)
                         telemetry=telemetry.TelemetryConfig(
-                            level="trace", lat_lo=0.01, lat_hi=1e4))
+                            level="histogram", lat_lo=0.01, lat_hi=1e4))
     state = init_kv_store_batch(cfg, 2)
     remote = jnp.zeros((128, 16, 4, 64), jnp.bfloat16)
     rng = np.random.default_rng(0)

@@ -628,8 +628,9 @@ def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
         fab, nic = serve_wb(fab, nic, mc, wb)
         return (eng, fab, nic), wb
 
-    (eng, fab, nic), wrote_back = jax.lax.scan(
-        wb_one, (seq.eng, fab, nic), evicted)
+    with jax.named_scope("store.writeback_scan"):
+        (eng, fab, nic), wrote_back = jax.lax.scan(
+            wb_one, (seq.eng, fab, nic), evicted)
     n_wb = jnp.sum(wrote_back)
 
     def sched_one(carry, i):
@@ -675,8 +676,9 @@ def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
         stall = jnp.where(miss, jnp.maximum(served_at - clock, 0.0), 0.0)
         return (eng, fab, nic), (do_line, do_page, stall)
 
-    (eng, fab, nic), (line_sent, scheduled, stalls) = jax.lax.scan(
-        sched_one, (eng, fab, nic), jnp.arange(r))
+    with jax.named_scope("store.request_scan"):
+        (eng, fab, nic), (line_sent, scheduled, stalls) = jax.lax.scan(
+            sched_one, (eng, fab, nic), jnp.arange(r))
 
     n_sub = jnp.sum(line_sent)
     n_sched = jnp.sum(scheduled)
@@ -773,25 +775,28 @@ def step_fetch(state: KVStoreState, cfg: KVStoreConfig,
     writes = _writes_or_zero(needed_pages, needed_writes)
     pol = _policy_or_cfg(cfg, policy)
     clock = state.clock + 1.0
-    if cfg.kernel_impl == "chain":
-        seq, evicted = _land(state.seq, cfg, remote_k, remote_v, clock,
-                             pol)
-        seq, k_local, v_local, local_hit = _lookup(seq, clock,
-                                                   needed_pages, writes,
-                                                   pol)
-    else:
-        seqs = jax.tree.map(lambda x: x[None], state.seq)
-        out = _transact(seqs, cfg, remote_k, remote_v, clock, pol,
-                        needed_pages[None], writes[None])
-        seq, evicted, k_local, v_local, local_hit = jax.tree.map(
-            lambda x: x[0], out)
-    k_remote, v_remote = _remote_fetch(remote_k, remote_v, needed_pages,
-                                       jnp.any(~local_hit))
-    sel = local_hit[:, None, None, None]
-    k = jnp.where(sel, k_local.astype(k_remote.dtype), k_remote)
-    v = jnp.where(sel, v_local.astype(v_remote.dtype), v_remote)
-    seq, fab, _ = _schedule(seq, state.fab, cfg, needed_pages, offs,
-                            local_hit, clock, evicted)
+    with jax.named_scope("store.transact"):
+        if cfg.kernel_impl == "chain":
+            seq, evicted = _land(state.seq, cfg, remote_k, remote_v,
+                                 clock, pol)
+            seq, k_local, v_local, local_hit = _lookup(seq, clock,
+                                                       needed_pages,
+                                                       writes, pol)
+        else:
+            seqs = jax.tree.map(lambda x: x[None], state.seq)
+            out = _transact(seqs, cfg, remote_k, remote_v, clock, pol,
+                            needed_pages[None], writes[None])
+            seq, evicted, k_local, v_local, local_hit = jax.tree.map(
+                lambda x: x[0], out)
+    with jax.named_scope("store.serve"):
+        k_remote, v_remote = _remote_fetch(remote_k, remote_v,
+                                           needed_pages, jnp.any(~local_hit))
+        sel = local_hit[:, None, None, None]
+        k = jnp.where(sel, k_local.astype(k_remote.dtype), k_remote)
+        v = jnp.where(sel, v_local.astype(v_remote.dtype), v_remote)
+    with jax.named_scope("store.schedule_fold"):
+        seq, fab, _ = _schedule(seq, state.fab, cfg, needed_pages, offs,
+                                local_hit, clock, evicted)
     return KVStoreState(seq=seq, fab=fab, clock=clock), k, v, local_hit
 
 
@@ -815,34 +820,37 @@ def step_fetch_batch(state: BatchedKVStoreState, cfg: KVStoreConfig,
     writes = _writes_or_zero(needed_pages, needed_writes)
     pol = _policy_or_cfg(cfg, policy)
     clock = state.clock + 1.0
-    if cfg.kernel_impl == "chain":
-        seqs, evicted = jax.vmap(
-            lambda s: _land(s, cfg, remote_k, remote_v, clock, pol))(
-                state.seqs)
-        seqs, k_local, v_local, local_hit = jax.vmap(
-            lambda s, need, wr: _lookup(s, clock, need, wr, pol))(
-                seqs, needed_pages, writes)
-    else:
-        seqs, evicted, k_local, v_local, local_hit = _transact(
-            state.seqs, cfg, remote_k, remote_v, clock, pol,
-            needed_pages, writes)
-    k_remote, v_remote = _remote_fetch(remote_k, remote_v,
-                                       needed_pages.reshape(-1),
-                                       jnp.any(~local_hit))
-    k_remote = k_remote.reshape((b, r) + tuple(k_remote.shape[1:]))
-    v_remote = v_remote.reshape((b, r) + tuple(v_remote.shape[1:]))
-    sel = local_hit[:, :, None, None, None]
-    k = jnp.where(sel, k_local.astype(k_remote.dtype), k_remote)
-    v = jnp.where(sel, v_local.astype(v_remote.dtype), v_remote)
+    with jax.named_scope("store.transact"):
+        if cfg.kernel_impl == "chain":
+            seqs, evicted = jax.vmap(
+                lambda s: _land(s, cfg, remote_k, remote_v, clock, pol))(
+                    state.seqs)
+            seqs, k_local, v_local, local_hit = jax.vmap(
+                lambda s, need, wr: _lookup(s, clock, need, wr, pol))(
+                    seqs, needed_pages, writes)
+        else:
+            seqs, evicted, k_local, v_local, local_hit = _transact(
+                state.seqs, cfg, remote_k, remote_v, clock, pol,
+                needed_pages, writes)
+    with jax.named_scope("store.serve"):
+        k_remote, v_remote = _remote_fetch(remote_k, remote_v,
+                                           needed_pages.reshape(-1),
+                                           jnp.any(~local_hit))
+        k_remote = k_remote.reshape((b, r) + tuple(k_remote.shape[1:]))
+        v_remote = v_remote.reshape((b, r) + tuple(v_remote.shape[1:]))
+        sel = local_hit[:, :, None, None, None]
+        k = jnp.where(sel, k_local.astype(k_remote.dtype), k_remote)
+        v = jnp.where(sel, v_local.astype(v_remote.dtype), v_remote)
 
     def sched_seq(fab, xs):
         seq, need, off, hit, ev = xs
         seq, fab, _ = _schedule(seq, fab, cfg, need, off, hit, clock, ev)
         return fab, seq
 
-    fab, seqs = jax.lax.scan(sched_seq, state.fab,
-                             (seqs, needed_pages, offs, local_hit,
-                              evicted))
+    with jax.named_scope("store.schedule_fold"):
+        fab, seqs = jax.lax.scan(sched_seq, state.fab,
+                                 (seqs, needed_pages, offs, local_hit,
+                                  evicted))
     return (BatchedKVStoreState(seqs=seqs, fab=fab, clock=clock),
             k, v, local_hit)
 
@@ -883,25 +891,29 @@ def step_fetch_replicated(state: ReplicatedKVStoreState,
     active = (c > 1) if active is None else active
     pol = _policy_or_cfg(cfg, policy)
     clock = state.clock + 1.0
-    if cfg.kernel_impl == "chain":
-        seqs, evicted = jax.vmap(
-            lambda s: _land(s, cfg, remote_k, remote_v, clock, pol))(
-                state.seqs)
-        seqs, k_local, v_local, local_hit = jax.vmap(
-            lambda s, need, wr: _lookup(s, clock, need, wr, pol))(
-                seqs, flat, writes)
-    else:
-        seqs, evicted, k_local, v_local, local_hit = _transact(
-            state.seqs, cfg, remote_k, remote_v, clock, pol, flat,
-            writes)
-    k_remote, v_remote = _remote_fetch(remote_k, remote_v,
-                                       flat.reshape(-1),
-                                       jnp.any(~local_hit))
-    k_remote = k_remote.reshape((c * b, r) + tuple(k_remote.shape[1:]))
-    v_remote = v_remote.reshape((c * b, r) + tuple(v_remote.shape[1:]))
-    sel = local_hit[:, :, None, None, None]
-    k = jnp.where(sel, k_local.astype(k_remote.dtype), k_remote)
-    v = jnp.where(sel, v_local.astype(v_remote.dtype), v_remote)
+    with jax.named_scope("store.transact"):
+        if cfg.kernel_impl == "chain":
+            seqs, evicted = jax.vmap(
+                lambda s: _land(s, cfg, remote_k, remote_v, clock, pol))(
+                    state.seqs)
+            seqs, k_local, v_local, local_hit = jax.vmap(
+                lambda s, need, wr: _lookup(s, clock, need, wr, pol))(
+                    seqs, flat, writes)
+        else:
+            seqs, evicted, k_local, v_local, local_hit = _transact(
+                state.seqs, cfg, remote_k, remote_v, clock, pol, flat,
+                writes)
+    with jax.named_scope("store.serve"):
+        k_remote, v_remote = _remote_fetch(remote_k, remote_v,
+                                           flat.reshape(-1),
+                                           jnp.any(~local_hit))
+        k_remote = k_remote.reshape((c * b, r)
+                                    + tuple(k_remote.shape[1:]))
+        v_remote = v_remote.reshape((c * b, r)
+                                    + tuple(v_remote.shape[1:]))
+        sel = local_hit[:, :, None, None, None]
+        k = jnp.where(sel, k_local.astype(k_remote.dtype), k_remote)
+        v = jnp.where(sel, v_local.astype(v_remote.dtype), v_remote)
 
     def sched_seq(carry, xs):
         fab, nic = carry
@@ -910,9 +922,10 @@ def step_fetch_replicated(state: ReplicatedKVStoreState,
                                   ev, nic=nic, cu=cu, active=active)
         return (fab, nic), seq
 
-    (fab, nic), seqs = jax.lax.scan(
-        sched_seq, (state.fab, state.nic),
-        (seqs, flat, offs, local_hit, evicted, cus))
+    with jax.named_scope("store.schedule_fold"):
+        (fab, nic), seqs = jax.lax.scan(
+            sched_seq, (state.fab, state.nic),
+            (seqs, flat, offs, local_hit, evicted, cus))
     kv_shape = (c, b, r) + tuple(k_remote.shape[2:])
     return (ReplicatedKVStoreState(seqs=seqs, fab=fab, nic=nic,
                                    clock=clock),

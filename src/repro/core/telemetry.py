@@ -25,16 +25,15 @@ gates them:
 
 Gating mirrors the ``kernel_impl`` lattice (DESIGN.md §9/§10): the
 STATIC ``TelemetryConfig.level`` axis — ``off`` < ``counters`` <
-``histogram`` < ``trace`` — decides at trace time which instruments
-exist. ``off`` yields ``init_state(...) is None``: ``None`` is a leafless
-pytree, so a ``tel=None`` field on ``SimState``/``SeqState`` adds ZERO
-ops and ZERO leaves to the compiled program — bit-identity with the
-pre-telemetry outputs and unchanged compile counts are structural, not
-best-effort (pinned by goldens + compile-count tests). ``counters``
-turns on the series ring, ``histogram`` adds the latency histogram,
-``trace`` additionally asks host loops to record spans for the Perfetto
-export (a host-side concern; in-trace cost is identical to
-``histogram``).
+``histogram`` — decides at trace time which instruments exist. ``off``
+yields ``init_state(...) is None``: ``None`` is a leafless pytree, so a
+``tel=None`` field on ``SimState``/``SeqState`` adds ZERO ops and ZERO
+leaves to the compiled program — bit-identity with the pre-telemetry
+outputs and unchanged compile counts are structural, not best-effort
+(pinned by goldens + compile-count tests). ``counters`` turns on the
+series ring, ``histogram`` adds the latency histogram. Host spans are no
+level: a loop records them when its caller passes a
+``repro.runtime.obs.SpanRecorder``.
 
 The bin EDGES ride inside ``TelemetryState`` as a constant (BINS+1,)
 leaf rather than being recomputed from config at every consumer: state
@@ -52,7 +51,7 @@ import numpy as np
 F32 = jnp.float32
 
 # the level lattice, in order: each level includes everything below it
-LEVELS = ("off", "counters", "histogram", "trace")
+LEVELS = ("off", "counters", "histogram")
 
 
 @dataclass(frozen=True)
@@ -87,15 +86,11 @@ class TelemetryConfig:
 
     @property
     def series_on(self) -> bool:
-        return self.level in ("counters", "histogram", "trace")
+        return self.level in ("counters", "histogram")
 
     @property
     def histogram_on(self) -> bool:
-        return self.level in ("histogram", "trace")
-
-    @property
-    def trace_on(self) -> bool:
-        return self.level == "trace"
+        return self.level == "histogram"
 
 
 class TelemetryState(NamedTuple):

@@ -7,10 +7,13 @@ through the compiled programs); this module is the untraced half — what
 runs on the host around the jitted steps:
 
 - ``SpanRecorder``: wall-clock "X" (complete) span events around host
-  loop phases (prefill / decode / kv steps). Recording a span blocks on
-  its outputs (the caller passes them to ``span(..., sync=...)``), so
-  span durations are real compute, not async dispatch time — which is
-  why span capture only turns on at ``TelemetryConfig.level="trace"``.
+  loop phases (prefill / decode / kv steps), recorded only when the
+  caller passes a recorder. Recording a span blocks on its outputs (the
+  caller passes them to ``span(..., sync=...)``), so span durations are
+  real compute, not async dispatch time. Each span also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name, so under a profiler
+  trace it lands in the ``.xplane.pb`` on the device trace's clock,
+  beside the device ops it dispatched.
 - ``trace_export``: assembles spans + telemetry series into one Chrome
   trace-event JSON document (``{"traceEvents": [...]}`` with "X" spans
   and "C" counter tracks) that drags straight into https://ui.perfetto.
@@ -39,7 +42,8 @@ class SpanRecorder:
     """Collects Chrome trace "X" (complete) events on a host wall clock
     relative to construction time. `span(...)` optionally blocks on a
     pytree of outputs before closing, so the recorded duration covers
-    the device work the phase dispatched."""
+    the device work the phase dispatched; the same interval is a
+    `jax.profiler.TraceAnnotation` for a profiler trace, if one runs."""
 
     def __init__(self, pid: int = 0):
         self.pid = pid
@@ -53,17 +57,18 @@ class SpanRecorder:
     def span(self, name: str, tid: int = 0, **args):
         t_start = self._now_us()
         sync = {}
-        try:
-            yield sync
-        finally:
-            if "sync" in sync and sync["sync"] is not None:
-                jax.block_until_ready(sync["sync"])
-            self.events.append({
-                "name": name, "ph": "X", "ts": t_start,
-                "dur": self._now_us() - t_start,
-                "pid": self.pid, "tid": tid,
-                "args": {k: _jsonable(v) for k, v in args.items()},
-            })
+        with jax.profiler.TraceAnnotation(name):
+            try:
+                yield sync
+            finally:
+                if "sync" in sync and sync["sync"] is not None:
+                    jax.block_until_ready(sync["sync"])
+                self.events.append({
+                    "name": name, "ph": "X", "ts": t_start,
+                    "dur": self._now_us() - t_start,
+                    "pid": self.pid, "tid": tid,
+                    "args": {k: _jsonable(v) for k, v in args.items()},
+                })
 
     def instant(self, name: str, tid: int = 0, **args):
         self.events.append({

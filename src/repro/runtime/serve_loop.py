@@ -65,22 +65,11 @@ class PagedServeConfig:
     pages_per_seq: int = 32   # remote-tier pages reserved per tenant
 
 
-def _maybe_recorder(recorder, store_cfg):
-    """The serve loops' span-capture policy: record when the caller
-    passes a `repro.runtime.obs.SpanRecorder`, or auto-create one when
-    the store's telemetry level is "trace" (the spans then come back in
-    the ledger as `trace_spans`). Span durations block on the phase's
-    outputs, so trace-level runs serialize the dispatch pipeline —
-    that cost is the reason span capture is the TOP telemetry level."""
-    if recorder is None and store_cfg is not None \
-            and store_cfg.telemetry.trace_on:
-        from repro.runtime.obs import SpanRecorder
-        recorder = SpanRecorder()
-    return recorder
-
-
 def _span(rec, name, **args):
-    """`rec.span(...)` or a no-op context yielding a writable dict."""
+    """`rec.span(...)` or a no-op context yielding a writable dict. The
+    loops record spans only into a `repro.runtime.obs.SpanRecorder` the
+    caller passes; a span blocks on the phase's outputs, so recording
+    serializes the dispatch pipeline."""
     return nullcontext({}) if rec is None else rec.span(name, **args)
 
 
@@ -186,16 +175,14 @@ def serve_batch_paged(params, cfg: ArchConfig, prompts, scfg: ServeConfig,
     serving analogue of `StragglerDetector.should_reshard`).
 
     `recorder` (optional `repro.runtime.obs.SpanRecorder`) captures
-    prefill/decode-step spans; with `store_cfg.telemetry.level="trace"`
-    one is auto-created and the spans come back in the ledger as
-    `trace_spans` (ready for `obs.trace_export`). The store's own
+    prefill/decode-step spans into its `events` (ready for
+    `obs.trace_export`). The store's own
     histogram/series telemetry rides in on `store_cfg.telemetry` like
     `kernel_impl` does — ledger percentile columns need no loop changes.
 
     Returns (tokens (B, P + max_new_tokens), ledger dict).
     """
     opt = opt or ModelOptions(remat="none")
-    recorder = _maybe_recorder(recorder, store_cfg)
     b, p = prompts.shape
     max_len = p + scfg.max_new_tokens
     state, _ = init_decode_state(cfg, b, max_len, opt)
@@ -262,8 +249,6 @@ def serve_batch_paged(params, cfg: ArchConfig, prompts, scfg: ServeConfig,
     led = store_ledger(kv)
     if health_monitor is not None:
         led["link_reshard_modules"] = sorted(reshard_advised)
-    if recorder is not None:
-        led["trace_spans"] = recorder.events
     if kv.seqs.tel is not None:
         # raw per-tenant telemetry state (jnp pytree, NOT json) for the
         # examples' obs export; json writers must pop it first
@@ -299,7 +284,6 @@ def serve_replicated(params, cfg: ArchConfig, prompts, scfg: ServeConfig,
     per-module `module_bytes` and per-replica `unit_bytes`).
     """
     opt = opt or ModelOptions(remat="none")
-    recorder = _maybe_recorder(recorder, store_cfg)
     c = num_replicas
     b, p = prompts.shape
     flat_prompts = jnp.tile(prompts, (c, 1))             # (C*B, P)
@@ -368,8 +352,6 @@ def serve_replicated(params, cfg: ArchConfig, prompts, scfg: ServeConfig,
         sp["sync"] = (tok, kv.fab.page_busy)
     tokens = jnp.concatenate(out + gen, axis=1)
     led = store_ledger(kv)
-    if recorder is not None:
-        led["trace_spans"] = recorder.events
     if kv.seqs.tel is not None:
         led["_tel"] = kv.seqs.tel
     return tokens.reshape((c, b, -1)), led

@@ -211,196 +211,219 @@ def make_step(flags, cfg: SimConfig, net, comp_ratio, warm_after,
 
     def step(st: SimState, inp):
         page, off, gap, wr = inp
-        want_page = (fl.move_pages | fl.page_free) & fl.use_local_mem
+        with jax.named_scope("desim.engine"):
+            want_page = (fl.move_pages | fl.page_free) & fl.use_local_mem
 
         # ---- compute-unit sharding (page-hash -> per-unit streams over
         # the shared footprint; active_cu == 1 routes all to unit 0) ----
-        cu = compute_plane.shard_unit(page, active_cu)
-        nic_on = active_cu > 1            # NIC leg gate (idle at C=1)
-        ring_u = st.ring[cu]
-        res_u = compute_plane.unit_slice(st.res, cu)
-        eng = compute_plane.unit_slice(st.eng, cu)
+        with jax.named_scope("desim.issue"):
+            cu = compute_plane.shard_unit(page, active_cu)
+            nic_on = active_cu > 1            # NIC leg gate (idle at C=1)
+        with jax.named_scope("desim.unit_state"):
+            ring_u = st.ring[cu]
+            res_u = compute_plane.unit_slice(st.res, cu)
+            eng = compute_plane.unit_slice(st.eng, cu)
 
         # ---- core issue (MLP window, per-unit clock + ring) ----
-        oldest = jnp.min(ring_u)
-        slot = jnp.argmin(ring_u)
-        t_issue = jnp.maximum(st.t[cu] + gap, oldest)
+        with jax.named_scope("desim.issue"):
+            oldest = jnp.min(ring_u)
+            slot = jnp.argmin(ring_u)
+            t_issue = jnp.maximum(st.t[cu] + gap, oldest)
 
         # ---- local memory lookup (the unit's own residency tier) ----
-        set_idx = residency.set_index(res_u, page)
-        present, way, ready_ok = residency.lookup_one(res_u, set_idx,
-                                                      page, t_issue)
-        is_hit = (present & ready_ok & fl.use_local_mem) | fl.local_only
-        inflight_tbl = present & ~ready_ok
+        with jax.named_scope("desim.lookup"):
+            set_idx = residency.set_index(res_u, page)
+            present, way, ready_ok = residency.lookup_one(res_u, set_idx,
+                                                          page, t_issue)
+            is_hit = (present & ready_ok & fl.use_local_mem) | fl.local_only
+            inflight_tbl = present & ~ready_ok
 
-        eng = retire_arrivals(eng, t_issue, lpp)
+            eng = retire_arrivals(eng, t_issue, lpp)
 
         # ---- engine decision (§4.2) ----
-        send_line, send_page = select_granularity(
-            eng, page, t_issue, selection_enabled=fl.selection,
-            always_both=~fl.selection)
-        page_found, pidx = find(eng.page_key, page)
-        pending_arrival = jnp.where(page_found, eng.page_arrival[pidx], BIG)
-        send_page = (send_page & want_page & ~is_hit & ~inflight_tbl
-                     & ~fl.local_only)
-        send_line = send_line & fl.move_lines & ~is_hit
-        line_only = ~fl.move_pages & ~fl.page_free   # line-only: always fetch
-        send_line = jnp.where(line_only, ~is_hit, send_line) & ~fl.local_only
+        with jax.named_scope("desim.engine"):
+            send_line, send_page = select_granularity(
+                eng, page, t_issue, selection_enabled=fl.selection,
+                always_both=~fl.selection)
+            page_found, pidx = find(eng.page_key, page)
+            pending_arrival = jnp.where(page_found, eng.page_arrival[pidx],
+                                        BIG)
+            send_page = (send_page & want_page & ~is_hit & ~inflight_tbl
+                         & ~fl.local_only)
+            send_line = send_line & fl.move_lines & ~is_hit
+            # line-only: always fetch
+            line_only = ~fl.move_pages & ~fl.page_free
+            send_line = (jnp.where(line_only, ~is_hit, send_line)
+                         & ~fl.local_only)
 
-        mc = fabric.place(fcfg, page)
-        sw = switch[mc]
-        t0 = t_issue + sw + trans_lat + remote_lat
+        with jax.named_scope("desim.fabric"):
+            mc = fabric.place(fcfg, page)
+            sw = switch[mc]
+            t0 = t_issue + sw + trans_lat + remote_lat
 
-        # ---- adaptive §4.1 repartitioning (controller before service:
-        # each fabric's carried per-module ratio is nudged toward its own
-        # observed backlog + the engines' buffer occupancies; `where`-gated
-        # on the traceable adaptive flag, so static schemes carry their
-        # seed ratio bit-identically) ----
-        # floored like occupy_busy's divide: a health-0 (hard-failed)
-        # segment must yield huge-but-finite latencies, not inf/NaN stats
-        bw = jnp.maximum(fabric.link_bw_at(st.net.link, mc, t_issue), 1e-6)
-        sb_occ = utilization(eng.sb_key)
-        pg_occ = utilization(eng.page_key)
-        wire_b = jnp.where(fl.compress, page_b / comp_ratio, page_b)
-        net_fab = fabric.adapt_ratio_at(
-            st.net, mc, t_issue, adaptive=fl.adaptive,
-            r_idle=fl.bw_ratio, page_unit=wire_b,
-            line_occ=sb_occ, page_occ=pg_occ)
-        mem_fab = fabric.adapt_ratio_at(
-            st.mem, mc, t_issue, adaptive=fl.adaptive,
-            r_idle=fl.bw_ratio, page_unit=page_b,
-            line_occ=sb_occ, page_occ=pg_occ)
-        ratio = net_fab.ratio[mc]
-        line_share, page_share = bandwidth.shares(fl.partition, ratio)
-        mem_line_share, _ = bandwidth.shares(fl.partition,
-                                             mem_fab.ratio[mc])
+            # ---- adaptive §4.1 repartitioning (controller before
+            # service: each fabric's carried per-module ratio is nudged
+            # toward its own observed backlog + the engines' buffer
+            # occupancies; `where`-gated on the traceable adaptive flag,
+            # so static schemes carry their seed ratio bit-identically) ----
+            # floored like occupy_busy's divide: a health-0 (hard-failed)
+            # segment must yield huge-but-finite latencies, not inf/NaN
+            bw = jnp.maximum(fabric.link_bw_at(st.net.link, mc, t_issue),
+                             1e-6)
+            sb_occ = utilization(eng.sb_key)
+            pg_occ = utilization(eng.page_key)
+            wire_b = jnp.where(fl.compress, page_b / comp_ratio, page_b)
+            net_fab = fabric.adapt_ratio_at(
+                st.net, mc, t_issue, adaptive=fl.adaptive,
+                r_idle=fl.bw_ratio, page_unit=wire_b,
+                line_occ=sb_occ, page_occ=pg_occ)
+            mem_fab = fabric.adapt_ratio_at(
+                st.mem, mc, t_issue, adaptive=fl.adaptive,
+                r_idle=fl.bw_ratio, page_unit=page_b,
+                line_occ=sb_occ, page_occ=pg_occ)
+            ratio = net_fab.ratio[mc]
+            line_share, page_share = bandwidth.shares(fl.partition, ratio)
+            mem_line_share, _ = bandwidth.shares(fl.partition,
+                                                 mem_fab.ratio[mc])
 
-        comp_delay = jnp.where(fl.compress, comp_lat, 0.0)
-        move_page_physically = send_page & ~fl.page_free
+            comp_delay = jnp.where(fl.compress, comp_lat, 0.0)
+            move_page_physically = send_page & ~fl.page_free
 
-        # ---- remote-memory bus then network link: each a dual-granularity
-        # channel bank on the shared fabric (partitioned virtual channels
-        # or one shared FIFO per module, at the LinkModel bandwidth
-        # sampled at this request's issue time). The network leg is priced
-        # on TWO endpoints — the shared module bank and the requesting
-        # unit's NIC bank (arrival = later completion); the NIC leg idles
-        # when only one unit is active (bit-identical seed path) ----
-        mem_fab, lm_done, pm_done = fabric.serve_dual_at(
-            mem_fab, mc, partition=fl.partition, now=t_issue,
-            line_ready=t0, line_bytes=line_b, line_gate=send_line,
-            page_ready=t0, page_bytes=page_b, page_gate=move_page_physically)
-        (net_fab, nic_fab, ln_done, pn_done, _,
-         pn_done_mod) = compute_plane.serve_dual_two_leg(
-            net_fab, st.nic, mc, cu, partition=fl.partition, now=t_issue,
-            line_ready=lm_done, line_bytes=line_b, line_gate=send_line,
-            page_ready=pm_done + comp_delay, page_bytes=wire_b,
-            page_gate=move_page_physically, active=nic_on)
-        line_arrival = jnp.where(send_line, ln_done + sw, BIG)
-        # "issued" (left the page queue) = network transmission start on
-        # the MODULE channel — until then a later line request can still
-        # win the race (§4.2)
-        pn_start = pn_done_mod - wire_b / jnp.maximum(bw * page_share, 1e-6)
-        page_arrival = jnp.where(move_page_physically,
-                                 pn_done + sw + comp_delay, BIG)
-        # page-free: materializes at the cost of one line-granularity access
-        free_t = (t_issue + 2 * sw + trans_lat
-                  + remote_lat + line_b / bw + line_b / membw)
-        page_arrival = jnp.where(fl.page_free & send_page, free_t,
-                                 page_arrival)
+            # ---- remote-memory bus then network link: each a
+            # dual-granularity channel bank on the shared fabric
+            # (partitioned virtual channels or one shared FIFO per module,
+            # at the LinkModel bandwidth sampled at this request's issue
+            # time). The network leg is priced on TWO endpoints — the
+            # shared module bank and the requesting unit's NIC bank
+            # (arrival = later completion); the NIC leg idles when only
+            # one unit is active (bit-identical seed path) ----
+            mem_fab, lm_done, pm_done = fabric.serve_dual_at(
+                mem_fab, mc, partition=fl.partition, now=t_issue,
+                line_ready=t0, line_bytes=line_b, line_gate=send_line,
+                page_ready=t0, page_bytes=page_b,
+                page_gate=move_page_physically)
+            (net_fab, nic_fab, ln_done, pn_done, _,
+             pn_done_mod) = compute_plane.serve_dual_two_leg(
+                net_fab, st.nic, mc, cu, partition=fl.partition,
+                now=t_issue,
+                line_ready=lm_done, line_bytes=line_b, line_gate=send_line,
+                page_ready=pm_done + comp_delay, page_bytes=wire_b,
+                page_gate=move_page_physically, active=nic_on)
+            line_arrival = jnp.where(send_line, ln_done + sw, BIG)
+            # "issued" (left the page queue) = network transmission start
+            # on the MODULE channel — until then a later line request can
+            # still win the race (§4.2)
+            pn_start = pn_done_mod - wire_b / jnp.maximum(bw * page_share,
+                                                          1e-6)
+            page_arrival = jnp.where(move_page_physically,
+                                     pn_done + sw + comp_delay, BIG)
+            # page-free: materializes at the cost of one line-granularity
+            # access
+            free_t = (t_issue + 2 * sw + trans_lat
+                      + remote_lat + line_b / bw + line_b / membw)
+            page_arrival = jnp.where(fl.page_free & send_page, free_t,
+                                     page_arrival)
 
-        # ---- serve time ----
-        cand = jnp.minimum(jnp.minimum(line_arrival, page_arrival),
-                           pending_arrival)
-        untracked = (t_issue + 2 * sw + trans_lat
-                     + remote_lat + line_b / (bw * line_share)
-                     + line_b / (membw * mem_line_share))
-        cand = jnp.where(cand >= BIG / 2, untracked, cand)
-        done = jnp.where(is_hit, t_issue + local_lat, cand)
+            # ---- serve time ----
+            cand = jnp.minimum(jnp.minimum(line_arrival, page_arrival),
+                               pending_arrival)
+            untracked = (t_issue + 2 * sw + trans_lat
+                         + remote_lat + line_b / (bw * line_share)
+                         + line_b / (membw * mem_line_share))
+            cand = jnp.where(cand >= BIG / 2, untracked, cand)
+            done = jnp.where(is_hit, t_issue + local_lat, cand)
 
         # ---- engine bookkeeping (gated insertions) ----
-        eng = _gate_tree(send_page, eng,
-                         schedule_page(eng, page, pn_start, page_arrival))
-        eng = _gate_tree(send_line & fl.move_lines, eng,
-                         schedule_line(eng, page, off, line_arrival, lpp))
+        with jax.named_scope("desim.engine"):
+            eng = _gate_tree(send_page, eng,
+                             schedule_page(eng, page, pn_start, page_arrival))
+            eng = _gate_tree(send_line & fl.move_lines, eng,
+                             schedule_line(eng, page, off, line_arrival, lpp))
 
         # ---- residency update (insert page at the policy's victim in
         # the unit's OWN tier; writeback priced on both endpoints) ----
-        do_insert = send_page & fl.use_local_mem
-        victim = residency.evict_victim(res_u, set_idx, pol)
-        evict_page = res_u.page[set_idx, victim]
-        evict_dirty = res_u.dirty[set_idx, victim] & (evict_page >= 0)
-        wb = do_insert & evict_dirty
-        wb_bytes = jnp.where(wb, wire_b, 0.0)
-        net_fab, nic_fab, _ = compute_plane.serve_writeback_two_leg(
-            net_fab, nic_fab, mc, cu, t_issue, wire_b, gate=wb,
-            active=nic_on)
+        with jax.named_scope("desim.residency"):
+            do_insert = send_page & fl.use_local_mem
+            victim = residency.evict_victim(res_u, set_idx, pol)
+            evict_page = res_u.page[set_idx, victim]
+            evict_dirty = res_u.dirty[set_idx, victim] & (evict_page >= 0)
+            wb = do_insert & evict_dirty
+            wb_bytes = jnp.where(wb, wire_b, 0.0)
+            net_fab, nic_fab, _ = compute_plane.serve_writeback_two_leg(
+                net_fab, nic_fab, mc, cu, t_issue, wire_b, gate=wb,
+                active=nic_on)
 
-        res_u = residency.insert(res_u, set_idx, victim, page,
-                                 now=t_issue, ready=page_arrival,
-                                 dirty=wr, gate=do_insert)
-        res_u = residency.touch(res_u, set_idx, way, t_issue, pol,
-                                gate=is_hit & present)
-        res_u = residency.mark_dirty(res_u, set_idx, way, wr,
-                                     gate=is_hit & present)
+            res_u = residency.insert(res_u, set_idx, victim, page,
+                                     now=t_issue, ready=page_arrival,
+                                     dirty=wr, gate=do_insert)
+            res_u = residency.touch(res_u, set_idx, way, t_issue, pol,
+                                    gate=is_hit & present)
+            res_u = residency.mark_dirty(res_u, set_idx, way, wr,
+                                         gate=is_hit & present)
 
         # ---- stats (warmup-gated: first `warm_after` requests excluded
         # from latency/hit accounting; total_time still covers the run) ----
-        warm = st.stats["i"] >= warm_after
-        lat = jnp.where(warm, done - t_issue, 0.0)
-        served_line = (~is_hit) & (line_arrival <= jnp.minimum(
-            page_arrival, pending_arrival))
-        # paper's fig-10 metric: tag-present accesses count as local-memory
-        # hits (burst followers of an inflight page are served from local
-        # memory once it lands); the triggering first touch is a miss.
-        # Latency accounting is unaffected.
-        stat_hit = is_hit | inflight_tbl
-        stt = st.stats
-        stats = {
-            "i": stt["i"] + 1.0,
-            "n": stt["n"] + warm,
-            "hits": stt["hits"] + (stat_hit & warm),
-            "lat_sum": stt["lat_sum"] + lat,
-            "pages_moved": stt["pages_moved"] + move_page_physically,
-            "lines_moved": stt["lines_moved"] + send_line,
-            "net_bytes": stt["net_bytes"] + wb_bytes
-            + jnp.where(move_page_physically, wire_b, 0.0)
-            + jnp.where(send_line, line_b, 0.0),
-            "wb_bytes": stt["wb_bytes"] + wb_bytes,
-            "served_line": stt["served_line"] + served_line,
-            "served_page": stt["served_page"] + ((~is_hit) & ~served_line),
-            "page_drops": stt["page_drops"] + (
-                (~is_hit) & ~send_page & ~page_found & ~inflight_tbl
-                & want_page),
-            "dirty_evicts": stt["dirty_evicts"] + wb,
-            "evictions": stt["evictions"] + (do_insert & (evict_page >= 0)),
-        }
+        with jax.named_scope("desim.stats"):
+            warm = st.stats["i"] >= warm_after
+            lat = jnp.where(warm, done - t_issue, 0.0)
+            served_line = (~is_hit) & (line_arrival <= jnp.minimum(
+                page_arrival, pending_arrival))
+            # paper's fig-10 metric: tag-present accesses count as
+            # local-memory hits (burst followers of an inflight page are
+            # served from local memory once it lands); the triggering first
+            # touch is a miss. Latency accounting is unaffected.
+            stat_hit = is_hit | inflight_tbl
+            stt = st.stats
+            stats = {
+                "i": stt["i"] + 1.0,
+                "n": stt["n"] + warm,
+                "hits": stt["hits"] + (stat_hit & warm),
+                "lat_sum": stt["lat_sum"] + lat,
+                "pages_moved": stt["pages_moved"] + move_page_physically,
+                "lines_moved": stt["lines_moved"] + send_line,
+                "net_bytes": stt["net_bytes"] + wb_bytes
+                + jnp.where(move_page_physically, wire_b, 0.0)
+                + jnp.where(send_line, line_b, 0.0),
+                "wb_bytes": stt["wb_bytes"] + wb_bytes,
+                "served_line": stt["served_line"] + served_line,
+                "served_page": stt["served_page"]
+                + ((~is_hit) & ~served_line),
+                "page_drops": stt["page_drops"] + (
+                    (~is_hit) & ~send_page & ~page_found & ~inflight_tbl
+                    & want_page),
+                "dirty_evicts": stt["dirty_evicts"] + wb,
+                "evictions": stt["evictions"]
+                + (do_insert & (evict_page >= 0)),
+            }
 
-        # ---- telemetry plane (static level axis; None-transparent) ----
-        tel = st.tel
-        if telcfg is not None and telcfg.enabled:
-            # warm-gated end-to-end access latency (hit OR miss) — the
-            # same population `lat_sum`/`n` average, as a distribution
-            tel = telemetry.record_latency(tel, telcfg, done - t_issue,
-                                           gate=warm)
-            tel = telemetry.record_series(
-                tel, telcfg, stt["i"].astype(jnp.int32),
-                jnp.stack([
-                    fabric.backlog(net_fab, mc, t_issue)[1],
-                    ratio,
-                    stats["hits"] / jnp.maximum(stats["n"], 1.0),
-                    stats["evictions"],
-                    stats["wb_bytes"],
-                    jnp.mean(fabric.module_health(net_fab.link, t_issue)),
-                ]))
+            # ---- telemetry plane (static level axis; None-transparent) ----
+            tel = st.tel
+            if telcfg is not None and telcfg.enabled:
+                # warm-gated end-to-end access latency (hit OR miss) — the
+                # same population `lat_sum`/`n` average, as a distribution
+                tel = telemetry.record_latency(tel, telcfg, done - t_issue,
+                                               gate=warm)
+                tel = telemetry.record_series(
+                    tel, telcfg, stt["i"].astype(jnp.int32),
+                    jnp.stack([
+                        fabric.backlog(net_fab, mc, t_issue)[1],
+                        ratio,
+                        stats["hits"] / jnp.maximum(stats["n"], 1.0),
+                        stats["evictions"],
+                        stats["wb_bytes"],
+                        jnp.mean(fabric.module_health(net_fab.link,
+                                                      t_issue)),
+                    ]))
 
-        new_st = SimState(
-            t=st.t.at[cu].set(t_issue),
-            ring=st.ring.at[cu, slot].set(done),
-            res=compute_plane.unit_update(st.res, cu, res_u),
-            eng=compute_plane.unit_update(st.eng, cu, eng),
-            net=net_fab, mem=mem_fab, nic=nic_fab,
-            stats=stats, tel=tel,
-        )
+        with jax.named_scope("desim.unit_state"):
+            new_st = SimState(
+                t=st.t.at[cu].set(t_issue),
+                ring=st.ring.at[cu, slot].set(done),
+                res=compute_plane.unit_update(st.res, cu, res_u),
+                eng=compute_plane.unit_update(st.eng, cu, eng),
+                net=net_fab, mem=mem_fab, nic=nic_fab,
+                stats=stats, tel=tel,
+            )
         return new_st, done
 
     return step

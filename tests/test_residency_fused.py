@@ -1,6 +1,7 @@
 """Fused residency transaction: Pallas kernel (interpret) vs jnp oracle,
 fused-vs-chain store bit-identity, compile counts, BENCH schema."""
 import json
+import re
 from pathlib import Path
 
 import jax
@@ -189,6 +190,27 @@ def test_store_kernel_impl_single_compile():
             state, _, _, _ = fetch(state, need,
                                    residency.as_policy(pol_name))
         assert fetch._cache_size() == 1, impl
+
+
+STORE_SCOPES = ("store.transact", "store.serve", "store.schedule_fold",
+                "store.request_scan", "store.writeback_scan")
+
+
+@pytest.mark.parametrize("impl", ["ref", "chain"])
+def test_store_phase_scopes_in_compiled_step(impl):
+    """Every phase scope of the store step reaches the compiled
+    `step_fetch_batch`'s op_name metadata, whichever transaction path
+    runs: the benchmark reads each phase's device time by these names
+    (bench/scopes.py)."""
+    cfg = _mini_cfg(impl)
+    remote = jnp.zeros((32, 8, 2, 16), jnp.float32)
+    need = jnp.zeros((3, 2), jnp.int32)
+    text = jax.jit(lambda s, n: step_fetch_batch(
+        s, cfg, remote, remote, n, needed_writes=n > 0)).lower(
+            init_kv_store_batch(cfg, 3), need).compile().as_text()
+    op_names = "\n".join(re.findall(r'op_name="([^"]*)"', text))
+    for scope in STORE_SCOPES:
+        assert re.search(rf"[/(]{re.escape(scope)}[/)]", op_names), scope
 
 
 def test_checked_in_bench_jsons_match_producer_schema():

@@ -1,7 +1,10 @@
 """Simulator behavior: scheme sanity orderings + conservation + paper
 regime checks on short traces (full aggregates live in benchmarks/)."""
+import re
+
 import numpy as np
 import pytest
+from jax.extend.backend import get_backend
 
 from repro.core.params import NetworkParams
 from repro.sim.desim import SimConfig, make_net, simulate_grid
@@ -25,6 +28,25 @@ def results():
             for s in ("local", "remote", "page-free", "lc", "pq", "daemon",
                       "cache-line")}
     return out
+
+
+DESIM_SCOPES = ("desim.unit_state", "desim.issue", "desim.lookup",
+                "desim.engine", "desim.fabric", "desim.residency",
+                "desim.stats")
+
+
+def test_step_phase_scopes_in_compiled_lattice(results):
+    """Every phase scope of `make_step` reaches the op_name metadata of
+    the lattice compiled for `results` (read from the live executables,
+    so no second compile): the benchmark reads each phase's device time
+    by these names (bench/scopes.py)."""
+    texts = [m.to_string() for ex in get_backend().live_executables()
+             for m in ex.hlo_modules()
+             if m.name.startswith("jit__lattice_jit")]
+    assert texts
+    op_names = "\n".join(re.findall(r'op_name="([^"]*)"', "".join(texts)))
+    for scope in DESIM_SCOPES:
+        assert re.search(rf"[/(]{re.escape(scope)}[/)]", op_names), scope
 
 
 def test_local_is_fastest(results):
