@@ -267,7 +267,8 @@ class ReplicatedKVStoreState(NamedTuple):
 
 STAT_KEYS = ("sub_block_fetches", "page_moves", "wire_bytes",
              "uncompressed_bytes", "local_hits", "requests", "stall_steps",
-             "writeback_bytes", "dirty_evicts", "evictions")
+             "writeback_bytes", "dirty_evicts", "evictions",
+             "writeback_offers")
 
 # per-decode-step series channels the telemetry ring samples (the
 # post-schedule fabric/stats view of the sequence's step)
@@ -540,9 +541,68 @@ def _remote_fetch(remote_k, remote_v, pages_flat, any_miss):
 
 
 # ---------------------------------------------------------- scheduling
+def _writeback_offer(cfg: KVStoreConfig, clock, *, two_leg: bool,
+                     cu=None, active=True):
+    """The §4.3 offer of ONE dirty eviction, as a loop body
+    `wb_one((eng, fab, nic), pid) -> ((eng', fab', nic'), wrote_back)`:
+    the page goes to the dirty unit (`note_dirty_eviction`) and, when not
+    buffered, is serialized on its module's writeback channel (and on
+    unit `cu`'s NIC when `two_leg`). A `pid < 0` offer is an identity."""
+    dp = cfg.daemon
+    page_wire = _wire_bytes(cfg, cfg.page_tokens, cfg.compress_pages)
+
+    if two_leg:
+        def serve_wb(fab, nic, mc, gate):
+            fab, nic, _ = compute_plane.serve_writeback_two_leg(
+                fab, nic, mc, cu, clock, page_wire, gate=gate,
+                active=active)
+            return fab, nic
+    else:
+        def serve_wb(fab, nic, mc, gate):
+            fab, _ = fabric.serve_writeback_at(fab, mc, clock, page_wire,
+                                               gate=gate)
+            return fab, nic
+
+    def wb_one(carry, pid):
+        eng, fab, nic = carry
+        ok = pid >= 0
+        mc = fabric.place(cfg.fabric, jnp.maximum(pid, 0))
+        new_eng, buffered = note_dirty_eviction(eng, pid, dp)
+        eng = _gate_tree(ok, eng, new_eng)
+        wb = ok & ~buffered
+        fab, nic = serve_wb(fab, nic, mc, wb)
+        return (eng, fab, nic), wb
+
+    return wb_one
+
+
+def _offer_evictions(wb_one, carry, evicted):
+    """Run `wb_one` over the valid (`>= 0`) entries of `evicted` (k,)
+    int32, in their order, one iteration each: a step with no dirty
+    eviction runs none. The -1 padding is skipped, not offered — an
+    offer of -1 is an identity, so the result is the one a scan over all
+    k entries gives. Returns (carry', n_wb, n_offers): the writebacks
+    that went on the wire and the offers made."""
+    k = evicted.shape[0]
+    valid = evicted >= 0
+    n_offers = jnp.sum(valid)
+    # compact the valid entries to the front, order kept (no sort)
+    rank = jnp.where(valid, jnp.cumsum(valid) - 1, k)
+    packed = jnp.full_like(evicted, -1).at[rank].set(evicted, mode="drop")
+
+    def body(i, c):
+        carry, n_wb = c
+        carry, wb = wb_one(carry, packed[i])
+        return carry, n_wb + wb
+
+    return (*jax.lax.fori_loop(0, n_offers, body,
+                               (carry, jnp.zeros_like(n_offers))),
+            n_offers)
+
+
 def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
-              needed_pages, needed_offsets, local_hit, clock,
-              evicted=None, nic=None, cu=None, active=True):
+              needed_pages, needed_offsets, local_hit, clock, evicted,
+              nic=None, cu=None, active=True):
     """Route every miss through the shared §4.2 selection unit and serve
     its transfers on the shared fabric (sequential within the step, so
     same-page requests dedup and queue exactly like the simulator).
@@ -567,7 +627,9 @@ def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
     evictions (from `_land`): each is offered to the §4.3 dirty unit
     (`note_dirty_eviction` — buffered if its page is inflight and under
     threshold, throttling past it) and, when not buffered, serialized on
-    the victim page's module writeback channel.
+    the victim page's module writeback channel. Only the valid entries
+    are visited (`_offer_evictions`); the `writeback_offers` stat counts
+    them.
 
     `nic`/`cu`/`active` switch on the compute plane's two-leg pricing:
     when a per-replica NIC bank is passed, every transfer (requests AND
@@ -591,11 +653,6 @@ def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
                 page_ready=clock, page_bytes=page_wire,
                 page_gate=page_gate)
             return fab, nic, line_done, page_done, page_done
-
-        def serve_wb(fab, nic, mc, gate):
-            fab, _ = fabric.serve_writeback_at(fab, mc, clock, page_wire,
-                                               gate=gate)
-            return fab, nic
     else:
         def serve(fab, nic, mc, *, line_gate, page_gate):
             fab, nic, line_done, page_done, _, pd_mod = \
@@ -607,31 +664,13 @@ def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
                     page_gate=page_gate, active=active)
             return fab, nic, line_done, page_done, pd_mod
 
-        def serve_wb(fab, nic, mc, gate):
-            fab, nic, _ = compute_plane.serve_writeback_two_leg(
-                fab, nic, mc, cu, clock, page_wire, gate=gate,
-                active=active)
-            return fab, nic
-
     # ---- dirty-eviction writebacks (pages written locally, now evicted:
     # §4.3 dirty unit first, writeback channel when not buffered) ----
-    if evicted is None:
-        evicted = jnp.full((0,), -1, jnp.int32)
-
-    def wb_one(carry, pid):
-        eng, fab, nic = carry
-        ok = pid >= 0
-        mc = fabric.place(cfg.fabric, jnp.maximum(pid, 0))
-        new_eng, buffered = note_dirty_eviction(eng, pid, dp)
-        eng = _gate_tree(ok, eng, new_eng)
-        wb = ok & ~buffered
-        fab, nic = serve_wb(fab, nic, mc, wb)
-        return (eng, fab, nic), wb
-
+    wb_one = _writeback_offer(cfg, clock, two_leg=nic is not None, cu=cu,
+                              active=active)
     with jax.named_scope("store.writeback_scan"):
-        (eng, fab, nic), wrote_back = jax.lax.scan(
+        (eng, fab, nic), n_wb, n_offers = _offer_evictions(
             wb_one, (seq.eng, fab, nic), evicted)
-    n_wb = jnp.sum(wrote_back)
 
     def sched_one(carry, i):
         eng, fab, nic = carry
@@ -698,6 +737,7 @@ def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
         "writeback_bytes": stt["writeback_bytes"] + n_wb * page_wire,
         "dirty_evicts": stt["dirty_evicts"] + n_wb,
         "evictions": stt["evictions"],     # accrued at landing (_land)
+        "writeback_offers": stt["writeback_offers"] + n_offers,
     }
 
     # ---- telemetry plane (DESIGN.md §10): recorded HERE, at the oracle

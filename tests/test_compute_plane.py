@@ -370,3 +370,106 @@ def test_store_writeback_throttles_through_dirty_unit():
     assert led["writeback_bytes"] > 0.0
     np.testing.assert_allclose(sum(led["module_bytes"]),
                                led["wire_bytes"], rtol=1e-5)
+
+
+# ------------------------------------- writeback loop over dirty evictions
+def _wb_cfg():
+    from repro.core.params import DaemonParams
+    return KVStoreConfig(num_local_pages=48, page_tokens=8, kv_heads=2,
+                         head_dim=16, page_budget_per_step=4,
+                         daemon=DaemonParams(inflight_page_buf=16,
+                                             dirty_flush_threshold=2),
+                         fabric=FabricConfig(num_modules=4))
+
+
+_HOLE = -1
+_WB_CASES = {
+    "all_empty": [_HOLE] * 16,
+    "holes": [3, _HOLE, _HOLE, 7, _HOLE, 12, 13, _HOLE, _HOLE, _HOLE,
+              21, _HOLE, _HOLE, _HOLE, 9, _HOLE],
+    "all_valid": list(range(20, 36)),
+    # page 5 is inflight: its dirty eviction is buffered, not written
+    "inflight_buffered": [_HOLE, 5, _HOLE, 11, _HOLE, _HOLE, _HOLE, _HOLE,
+                          _HOLE, _HOLE, _HOLE, _HOLE, _HOLE, _HOLE, 6,
+                          _HOLE],
+    # page 5 offered past dirty_flush_threshold (2): throttled, then on
+    "past_threshold": [5, _HOLE, 5, 5, _HOLE, 5, 17, _HOLE, 5, _HOLE,
+                       _HOLE, _HOLE, _HOLE, _HOLE, _HOLE, 5],
+}
+
+
+@pytest.mark.parametrize("two_leg", [False, True],
+                         ids=["single_endpoint", "two_leg"])
+@pytest.mark.parametrize("case", sorted(_WB_CASES))
+def test_writeback_loop_matches_full_scan(case, two_leg):
+    """The writeback loop visits only the valid evictions, yet leaves the
+    engine, the fabric, the NIC bank and the writeback count bitwise as a
+    scan over all 16 (-1 padded) slots does; it makes one offer per valid
+    entry."""
+    from repro.core.daemon_store import (_offer_evictions,
+                                         _writeback_offer)
+    from repro.core.engine import init_engine_state, schedule_page
+    cfg = _wb_cfg()
+    evicted = jnp.asarray(_WB_CASES[case], jnp.int32)
+    state = init_kv_store_replicated(cfg, 2, 1)
+    eng = schedule_page(init_engine_state(cfg.daemon), 5, 2.0, 9.0)
+    # a writeback channel already busy past the clock on one module
+    fab = state.fab._replace(wb_busy=state.fab.wb_busy.at[1].set(4.5))
+    nic = state.nic if two_leg else None
+    clock = jnp.float32(3.0)
+    wb_one = _writeback_offer(cfg, clock, two_leg=two_leg, cu=1,
+                              active=True)
+
+    @jax.jit
+    def full_scan(carry, ev):
+        carry, wrote_back = jax.lax.scan(wb_one, carry, ev)
+        return carry, jnp.sum(wrote_back)
+
+    want, want_wb = full_scan((eng, fab, nic), evicted)
+    got, got_wb, offers = jax.jit(
+        lambda c, ev: _offer_evictions(wb_one, c, ev))((eng, fab, nic),
+                                                       evicted)
+    assert got_wb.dtype == want_wb.dtype
+    assert int(got_wb) == int(want_wb)
+    assert int(offers) == sum(p >= 0 for p in _WB_CASES[case])
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if case == "all_valid":
+        assert int(got_wb) == 16
+    if case == "inflight_buffered":
+        assert int(got_wb) == 2           # 11 and 6; 5 is buffered
+    if case == "past_threshold":
+        assert int(got_wb) > 1            # page 5 throttled, 17 written
+
+
+def test_step_without_landing_leaves_writeback_state():
+    """A batched step in which nothing lands offers no eviction: the
+    writeback channels, their ledgers and every engine leaf stay bitwise
+    as the step found them, and `writeback_offers` adds 0."""
+    cfg = _wb_cfg()
+    remote = jnp.zeros((64, 8, 2, 16), jnp.float32)
+    step = jax.jit(lambda s, p, w: step_fetch_batch(s, cfg, remote,
+                                                    remote, p, None, w))
+    state = init_kv_store_batch(cfg, 3)
+    need = jnp.asarray([[0, 1], [8, 9], [16, 17]], jnp.int32)
+    writes = jnp.ones((3, 2), bool)
+    for _ in range(12):                    # land, then hit (and dirty)
+        state, _, _, hit = step(state, need, writes)
+    assert bool(hit.all())
+    assert int((state.seqs.eng.page_key >= 0).sum()) == 0   # none inflight
+    before = state
+    state, _, _, hit = step(state, need, writes)
+    assert bool(hit.all())
+    np.testing.assert_array_equal(np.asarray(state.fab.wb_busy),
+                                  np.asarray(before.fab.wb_busy))
+    np.testing.assert_array_equal(np.asarray(state.fab.wb_bytes),
+                                  np.asarray(before.fab.wb_bytes))
+    for a, b in zip(jax.tree.leaves(state.seqs.eng),
+                    jax.tree.leaves(before.seqs.eng)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(
+        np.asarray(state.seqs.stats["writeback_offers"]),
+        np.asarray(before.seqs.stats["writeback_offers"]))
+    assert ledger(state)["writeback_offers"] == 0.0
