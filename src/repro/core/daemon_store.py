@@ -48,6 +48,14 @@ across the batch, then scheduling folds over the batch in sequence order
 so all B engines contend for the same per-module channels
 deterministically. ``step_fetch`` is the single-sequence wrapper.
 
+Page layouts: a page is a K and a V tensor of (page_tokens, kv_heads,
+head_dim) each, or — for multi-head latent attention (DeepSeek-V2/V3's
+MLA) — ONE latent tensor of `latent_dim` values a token (the compressed
+c_KV and the shared RoPE key, which serve as key and value alike;
+`KVStoreConfig.latent_dim`). A latent store holds one pool, reads one
+tier tensor (passed as `remote_k`, with `remote_v=None`), serves k with
+v None, and prices its pages at `latent_dim` bfloat16 values a token.
+
 Replicated serving (the compute plane, ``repro.core.compute_plane``):
 ``step_fetch_replicated`` carries C serving replicas x B tenants each —
 C*B sequences — against ONE memory-side fabric plus a per-replica NIC
@@ -106,17 +114,19 @@ from repro.core.engine import (EngineState, find, gate_tree as _gate_tree,
 from repro.core.fabric import FabricConfig, FabricState, LinkModel
 from repro.core.params import DaemonParams
 from repro.kernels import ops
+from repro.kernels.residency_fused import LANES
 
 F32 = jnp.float32
 BIG = jnp.float32(3.0e38)
+LATENT_TILE = 16     # sublanes of a bfloat16 (16, 128) vector tile
 
 
 @dataclass(frozen=True)
 class KVStoreConfig:
     num_local_pages: int          # HBM pool slots (per sequence)
     page_tokens: int              # tokens per page
-    kv_heads: int
-    head_dim: int
+    kv_heads: int = 0             # K/V layout: heads a token (and below)
+    head_dim: int = 0
     daemon: DaemonParams = DaemonParams()
     compress_pages: bool = True   # int8 link compression on page moves
     page_budget_per_step: int = 4  # page-plane raw tokens drained per step
@@ -133,6 +143,12 @@ class KVStoreConfig:
     # (per-request stall), so lat_lo/lat_hi default to a step range.
     telemetry: telemetry.TelemetryConfig = telemetry.TelemetryConfig(
         lat_lo=0.01, lat_hi=1e4)
+    # page layout: 0 (default) = a K and a V tensor of (page_tokens,
+    # kv_heads, head_dim) a page; > 0 = ONE latent tensor of `latent_dim`
+    # values a token (MLA's compressed c_KV plus the shared RoPE key,
+    # which serves as key and value alike; kv_heads/head_dim stay 0),
+    # held token-major as whole (16, 128) bfloat16 tiles (`page_row`)
+    latent_dim: int = 0
 
     def __post_init__(self):
         if self.policy not in residency.POLICIES:
@@ -145,9 +161,31 @@ class KVStoreConfig:
         if self.pool_ways > 0 and self.num_local_pages % self.pool_ways:
             raise ValueError(f"pool_ways={self.pool_ways} must divide "
                              f"num_local_pages={self.num_local_pages}")
+        if self.latent_dim > 0:
+            if self.kv_heads or self.head_dim:
+                raise ValueError("a latent store has no K/V heads: leave "
+                                 "kv_heads and head_dim at 0")
+            if self.page_tokens * self.latent_dim % (LATENT_TILE * LANES):
+                raise ValueError(
+                    f"a latent page of {self.page_tokens} x "
+                    f"{self.latent_dim} values is not whole "
+                    f"({LATENT_TILE}, {LANES}) tiles")
+        elif self.kv_heads <= 0 or self.head_dim <= 0:
+            raise ValueError("a K/V store needs kv_heads and head_dim "
+                             "(or a latent_dim)")
 
     def policy_flags(self) -> residency.PolicyFlags:
         return residency.as_policy(self.policy)
+
+    def page_row(self) -> Tuple[int, ...]:
+        """Shape of one page of one tensor, as the pool, the remote tier
+        and the served rows hold it: (page_tokens, kv_heads, head_dim)
+        for K and for V, or (page_tokens * latent_dim / 128, 128) for a
+        latent page — a reshape of (page_tokens, latent_dim), in whole
+        tiles the kernels' DMAs move without a relayout."""
+        if self.latent_dim > 0:
+            return (self.page_tokens * self.latent_dim // LANES, LANES)
+        return (self.page_tokens, self.kv_heads, self.head_dim)
 
     def pool_geometry(self) -> Tuple[int, int]:
         """(sets, ways) of the local page table. The default (pool_ways
@@ -170,7 +208,8 @@ class SeqState(NamedTuple):
     """Per-sequence tier state. In a batched store every leaf carries a
     leading (B,) axis; the fabric is deliberately NOT in here — it is the
     shared resource the batch contends for."""
-    # local pool: (N, page, KV, D) x2 (k, v)
+    # local pool: (N, *cfg.page_row()) a tensor — k and v, or the latent
+    # page in `kpool` with `vpool` None (no leaves)
     kpool: jnp.ndarray
     vpool: jnp.ndarray
     # local page table: the shared residency tier (repro.core.residency),
@@ -268,7 +307,7 @@ class ReplicatedKVStoreState(NamedTuple):
 STAT_KEYS = ("sub_block_fetches", "page_moves", "wire_bytes",
              "uncompressed_bytes", "local_hits", "requests", "stall_steps",
              "writeback_bytes", "dirty_evicts", "evictions",
-             "writeback_offers")
+             "writeback_offers", "landings", "remote_rows")
 
 # per-decode-step series channels the telemetry ring samples (the
 # post-schedule fabric/stats view of the sequence's step)
@@ -283,11 +322,10 @@ KERNEL_IMPLS = ("auto", "pallas", "ref", "chain")
 
 
 def _init_seq(cfg: KVStoreConfig) -> SeqState:
-    n = cfg.num_local_pages
-    shape = (n, cfg.page_tokens, cfg.kv_heads, cfg.head_dim)
+    shape = (cfg.num_local_pages,) + cfg.page_row()
     return SeqState(
         kpool=jnp.zeros(shape, jnp.bfloat16),
-        vpool=jnp.zeros(shape, jnp.bfloat16),
+        vpool=None if cfg.latent_dim else jnp.zeros(shape, jnp.bfloat16),
         res=residency.init_residency(*cfg.pool_geometry()),
         eng=init_engine_state(cfg.daemon),
         stats={k: jnp.zeros((), F32) for k in STAT_KEYS},
@@ -347,6 +385,8 @@ def init_kv_store_replicated(cfg: KVStoreConfig, num_replicas: int,
 
 
 def _token_bytes(cfg: KVStoreConfig) -> float:
+    if cfg.latent_dim > 0:
+        return float(cfg.latent_dim * 2)               # one latent, bf16
     return float(cfg.kv_heads * cfg.head_dim * 2 * 2)  # k+v bf16
 
 
@@ -426,10 +466,10 @@ def _land(seq: SeqState, cfg: KVStoreConfig, remote_k, remote_v, clock,
         pick = order[:k_land]
         do = landed[pick]
         pids = landed_pages[pick]
-        page_k = ops.paged_gather(remote_k, jnp.maximum(pids, 0)).astype(
-            seq.kpool.dtype)
-        page_v = ops.paged_gather(remote_v, jnp.maximum(pids, 0)).astype(
-            seq.vpool.dtype)
+        pages = jax.tree.map(
+            lambda tier, pool: ops.paged_gather(
+                tier, jnp.maximum(pids, 0)).astype(pool.dtype),
+            (remote_k, remote_v), (seq.kpool, seq.vpool))
         sets, vways, ok = residency.landing_victims(seq.res, pids, pol)
         do = do & ok
         victims = sets * w_ways + vways              # flat pool slots
@@ -450,12 +490,9 @@ def _land(seq: SeqState, cfg: KVStoreConfig, remote_k, remote_v, clock,
         stats = {**seq.stats,
                  "evictions": seq.stats["evictions"]
                  + jnp.sum(do & resident)}
-        return seq._replace(
-            res=res,
-            stats=stats,
-            kpool=put(seq.kpool, page_k),
-            vpool=put(seq.vpool, page_v),
-        ), evicted
+        kpool, vpool = jax.tree.map(put, (seq.kpool, seq.vpool), pages)
+        return seq._replace(res=res, stats=stats, kpool=kpool,
+                            vpool=vpool), evicted
 
     seq, evicted = jax.lax.cond(jnp.any(landed), do_land,
                                 lambda s: (s, no_evict), seq)
@@ -481,8 +518,9 @@ def _lookup(seq: SeqState, clock, needed_pages, needed_writes,
                                                        clock)
     local_hit = present & ready_ok
     slot = set_idx * seq.res.page.shape[-1] + way    # flat pool slot
-    k_local = ops.paged_gather(seq.kpool, jnp.maximum(slot, 0))
-    v_local = ops.paged_gather(seq.vpool, jnp.maximum(slot, 0))
+    k_local, v_local = jax.tree.map(
+        lambda pool: ops.paged_gather(pool, jnp.maximum(slot, 0)),
+        (seq.kpool, seq.vpool))
     res = residency.touch(seq.res, set_idx, way, clock, pol,
                           gate=local_hit)
     res = residency.mark_dirty(res, set_idx, way, needed_writes,
@@ -526,18 +564,45 @@ def _remote_fetch(remote_k, remote_v, pages_flat, any_miss):
 
     `lax.cond` skips the gather entirely on 100%-hit steps (a real branch
     under jit and inside scan bodies — steady-state decode steps with a
-    warm pool do zero remote reads)."""
-    shape = (pages_flat.shape[0],) + tuple(remote_k.shape[1:])
+    warm pool do zero remote reads). Otherwise every request's row is
+    gathered (the `remote_rows` stat counts them). A latent tier passes
+    `remote_v` None and gets None back."""
+    tiers = (remote_k, remote_v)
+    n = pages_flat.shape[0]
 
     def hit_path(_):
-        return (jnp.zeros(shape, remote_k.dtype),
-                jnp.zeros(shape, remote_v.dtype))
+        return jax.tree.map(
+            lambda t: jnp.zeros((n,) + tuple(t.shape[1:]), t.dtype), tiers)
 
     def miss_path(_):
-        return (ops.paged_gather(remote_k, pages_flat),
-                ops.paged_gather(remote_v, pages_flat))
+        return jax.tree.map(lambda t: ops.paged_gather(t, pages_flat),
+                            tiers)
 
     return jax.lax.cond(any_miss, miss_path, hit_path, None)
+
+
+def _merge(local_hit, local, remote):
+    """Served rows: the pool's where the request hit, the tier's
+    elsewhere, for each page tensor of (k, v) (a None v stays None)."""
+    def one(loc, rem):
+        sel = local_hit.reshape(local_hit.shape
+                                + (1,) * (rem.ndim - local_hit.ndim))
+        return jnp.where(sel, loc.astype(rem.dtype), rem)
+    return jax.tree.map(one, local, remote)
+
+
+def _count_moves(before: SeqState, after: SeqState, rows) -> SeqState:
+    """Add a step's `landings` (pages written into the pool: each evicts
+    a resident page or fills an empty slot, and no slot empties again)
+    and `remote_rows` (rows `_remote_fetch` gathered for the sequence) to
+    its stats. Leaves may carry a batch axis."""
+    empty = lambda s: jnp.sum(s.res.page < 0, axis=(-2, -1)).astype(F32)
+    st = after.stats
+    landed = (st["evictions"] - before.stats["evictions"] + empty(before)
+              - empty(after))
+    return after._replace(stats={**st,
+                                 "landings": st["landings"] + landed,
+                                 "remote_rows": st["remote_rows"] + rows})
 
 
 # ---------------------------------------------------------- scheduling
@@ -736,7 +801,10 @@ def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
         "stall_steps": stt["stall_steps"] + jnp.mean(stalls),
         "writeback_bytes": stt["writeback_bytes"] + n_wb * page_wire,
         "dirty_evicts": stt["dirty_evicts"] + n_wb,
-        "evictions": stt["evictions"],     # accrued at landing (_land)
+        # accrued in the transaction: evictions, landings, remote_rows
+        "evictions": stt["evictions"],
+        "landings": stt["landings"],
+        "remote_rows": stt["remote_rows"],
         "writeback_offers": stt["writeback_offers"] + n_offers,
     }
 
@@ -802,7 +870,9 @@ def step_fetch(state: KVStoreState, cfg: KVStoreConfig,
     `policy` optionally overrides `cfg.policy` with TRACED flags
     (`_policy_or_cfg`) — a policy sweep reuses one compile per config.
 
-    Returns (state, k (R,page,KV,D), v, served_local (R,) bool).
+    Returns (state, k (R,page,KV,D), v, served_local (R,) bool). A latent
+    store (`cfg.latent_dim`) passes its one tier as `remote_k` with
+    `remote_v=None` and serves k (R, *cfg.page_row()) with v None.
     Misses are served via the sub-block plane from the remote tier now;
     page migrations drain through the shared fabric's per-module page
     channels and land when their (possibly congested) service completes.
@@ -811,6 +881,7 @@ def step_fetch(state: KVStoreState, cfg: KVStoreConfig,
     simulator's race rule).
     """
     needed_pages = jnp.asarray(needed_pages, jnp.int32)
+    r = needed_pages.shape[0]
     offs = _offsets_or_zero(needed_pages, needed_offsets)
     writes = _writes_or_zero(needed_pages, needed_writes)
     pol = _policy_or_cfg(cfg, policy)
@@ -829,11 +900,10 @@ def step_fetch(state: KVStoreState, cfg: KVStoreConfig,
             seq, evicted, k_local, v_local, local_hit = jax.tree.map(
                 lambda x: x[0], out)
     with jax.named_scope("store.serve"):
-        k_remote, v_remote = _remote_fetch(remote_k, remote_v,
-                                           needed_pages, jnp.any(~local_hit))
-        sel = local_hit[:, None, None, None]
-        k = jnp.where(sel, k_local.astype(k_remote.dtype), k_remote)
-        v = jnp.where(sel, v_local.astype(v_remote.dtype), v_remote)
+        any_miss = jnp.any(~local_hit)
+        remote = _remote_fetch(remote_k, remote_v, needed_pages, any_miss)
+        k, v = _merge(local_hit, (k_local, v_local), remote)
+        seq = _count_moves(state.seq, seq, (any_miss * r).astype(F32))
     with jax.named_scope("store.schedule_fold"):
         seq, fab, _ = _schedule(seq, state.fab, cfg, needed_pages, offs,
                                 local_hit, clock, evicted)
@@ -852,7 +922,8 @@ def step_fetch_batch(state: BatchedKVStoreState, cfg: KVStoreConfig,
     carry — so tenants contend for the same per-module channels and a
     hot module delays every sequence's landings, deterministically.
 
-    Returns (state, k (B,R,page,KV,D), v, served_local (B,R) bool).
+    Returns (state, k (B,R,page,KV,D), v, served_local (B,R) bool); a
+    latent store as in `step_fetch` (k (B,R,*cfg.page_row()), v None).
     """
     needed_pages = jnp.asarray(needed_pages, jnp.int32)
     b, r = needed_pages.shape
@@ -873,14 +944,13 @@ def step_fetch_batch(state: BatchedKVStoreState, cfg: KVStoreConfig,
                 state.seqs, cfg, remote_k, remote_v, clock, pol,
                 needed_pages, writes)
     with jax.named_scope("store.serve"):
-        k_remote, v_remote = _remote_fetch(remote_k, remote_v,
-                                           needed_pages.reshape(-1),
-                                           jnp.any(~local_hit))
-        k_remote = k_remote.reshape((b, r) + tuple(k_remote.shape[1:]))
-        v_remote = v_remote.reshape((b, r) + tuple(v_remote.shape[1:]))
-        sel = local_hit[:, :, None, None, None]
-        k = jnp.where(sel, k_local.astype(k_remote.dtype), k_remote)
-        v = jnp.where(sel, v_local.astype(v_remote.dtype), v_remote)
+        any_miss = jnp.any(~local_hit)
+        remote = jax.tree.map(
+            lambda x: x.reshape((b, r) + tuple(x.shape[1:])),
+            _remote_fetch(remote_k, remote_v, needed_pages.reshape(-1),
+                          any_miss))
+        k, v = _merge(local_hit, (k_local, v_local), remote)
+        seqs = _count_moves(state.seqs, seqs, (any_miss * r).astype(F32))
 
     def sched_seq(fab, xs):
         seq, need, off, hit, ev = xs
@@ -944,16 +1014,12 @@ def step_fetch_replicated(state: ReplicatedKVStoreState,
                 state.seqs, cfg, remote_k, remote_v, clock, pol, flat,
                 writes)
     with jax.named_scope("store.serve"):
-        k_remote, v_remote = _remote_fetch(remote_k, remote_v,
-                                           flat.reshape(-1),
-                                           jnp.any(~local_hit))
-        k_remote = k_remote.reshape((c * b, r)
-                                    + tuple(k_remote.shape[1:]))
-        v_remote = v_remote.reshape((c * b, r)
-                                    + tuple(v_remote.shape[1:]))
-        sel = local_hit[:, :, None, None, None]
-        k = jnp.where(sel, k_local.astype(k_remote.dtype), k_remote)
-        v = jnp.where(sel, v_local.astype(v_remote.dtype), v_remote)
+        any_miss = jnp.any(~local_hit)
+        remote = jax.tree.map(
+            lambda x: x.reshape((c * b, r) + tuple(x.shape[1:])),
+            _remote_fetch(remote_k, remote_v, flat.reshape(-1), any_miss))
+        k, v = _merge(local_hit, (k_local, v_local), remote)
+        seqs = _count_moves(state.seqs, seqs, (any_miss * r).astype(F32))
 
     def sched_seq(carry, xs):
         fab, nic = carry
@@ -966,11 +1032,11 @@ def step_fetch_replicated(state: ReplicatedKVStoreState,
         (fab, nic), seqs = jax.lax.scan(
             sched_seq, (state.fab, state.nic),
             (seqs, flat, offs, local_hit, evicted, cus))
-    kv_shape = (c, b, r) + tuple(k_remote.shape[2:])
+    k, v = jax.tree.map(lambda x: x.reshape((c, b, r) + x.shape[2:]),
+                        (k, v))
     return (ReplicatedKVStoreState(seqs=seqs, fab=fab, nic=nic,
                                    clock=clock),
-            k.reshape(kv_shape), v.reshape(kv_shape),
-            local_hit.reshape((c, b, r)))
+            k, v, local_hit.reshape((c, b, r)))
 
 
 def ledger(state) -> dict:

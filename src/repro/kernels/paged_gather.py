@@ -23,21 +23,23 @@ def _gather_kernel(idx_ref, pool_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_gather(pool, idx, *, interpret: bool = False):
-    """pool: (P, page, H, D); idx: (L,) int32 -> (L, page, H, D)."""
-    p, page, h, d = pool.shape
+    """pool: (P, *row); idx: (L,) int32 -> (L, *row). A row is a whole
+    page: (page, H, D) of K or V, or (rows, 128) of a latent page."""
+    row = tuple(pool.shape[1:])
+    rest = (0,) * len(row)
     l = idx.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(l,),
-        in_specs=[pl.BlockSpec((1, page, h, d),
-                               lambda i, idx_ref: (idx_ref[i], 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, page, h, d),
-                               lambda i, idx_ref: (i, 0, 0, 0)),
+        in_specs=[pl.BlockSpec((1,) + row,
+                               lambda i, idx_ref: (idx_ref[i],) + rest)],
+        out_specs=pl.BlockSpec((1,) + row,
+                               lambda i, idx_ref: (i,) + rest),
     )
     return pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((l, page, h, d), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((l,) + row, pool.dtype),
         interpret=interpret,
     )(idx, pool)
 

@@ -48,7 +48,7 @@ def bdi_decompress(base, deltas, ok, raw):
 
 
 def paged_gather(pool, idx):
-    """pool: (P, page, H, D); idx: (L,) int32 -> (L, page, H, D).
+    """pool: (P, *row); idx: (L,) int32 -> (L, *row).
 
     The DaeMon critical-path fetch: gather hot KV pages from the pool.
     """
@@ -77,17 +77,21 @@ def fused_residency_step(res, kpool, vpool, remote_k, remote_v, landed,
     tests/test_residency_fused.py); `residency_fused.fused_residency_step`
     is the Pallas kernel validated against this.
 
-    Batched: `res` leaves (B, S, W); kpool/vpool (B, N, page, KV, D) with
-    N = S*W flat slots; landed/landed_pages (B, P) from `poll_arrivals`;
-    needed_pages/needed_writes (B, R); remote_k/remote_v (PR, page, KV, D)
-    shared across the batch; `clock` scalar; `pol` traced PolicyFlags.
+    Batched: `res` leaves (B, S, W); kpool/vpool (B, N, *row) with
+    N = S*W flat slots (row = (page, KV, D) for a K/V pair); landed/
+    landed_pages (B, P) from `poll_arrivals`; needed_pages/needed_writes
+    (B, R); remote_k/remote_v (PR, *row) shared across the batch; `clock`
+    scalar; `pol` traced PolicyFlags. A latent store has one page tensor:
+    it passes `vpool = remote_v = None` and gets None for v back.
 
     Returns (res', kpool', vpool', evicted (B, k) i32 dirty-evicted page
-    ids (-1 pad), n_evictions (B,) f32, k_local/v_local (B, R, page, KV,
-    D), local_hit (B, R) bool). More than W same-set landings on one step
+    ids (-1 pad), n_evictions (B,) f32, k_local/v_local (B, R, *row),
+    local_hit (B, R) bool). More than W same-set landings on one step
     drop the overflow (the >N-landings rule); at S=1 this cannot happen.
     """
     pol = residency.as_policy(pol)
+    assert (vpool is None) == (remote_v is None)
+    tiers = (remote_k, remote_v)
 
     def one(res, kpool, vpool, landed, lpages, needed, writes):
         s_sets, w_ways = res.page.shape
@@ -96,16 +100,12 @@ def fused_residency_step(res, kpool, vpool, remote_k, remote_v, landed,
         no_evict = jnp.full((k_land,), -1, jnp.int32)
 
         def do_land(args):
-            res, kpool, vpool = args
+            res, pools = args
             order = jnp.argsort(jnp.logical_not(landed).astype(jnp.int32),
                                 stable=True)
             pick = order[:k_land]
             do = landed[pick]
             pids = lpages[pick]
-            page_k = paged_gather(remote_k, jnp.maximum(pids, 0)).astype(
-                kpool.dtype)
-            page_v = paged_gather(remote_v, jnp.maximum(pids, 0)).astype(
-                vpool.dtype)
             sets, vways, ok = residency.landing_victims(res, pids, pol)
             do = do & ok
             vict_page = res.page[sets, vways]
@@ -114,23 +114,28 @@ def fused_residency_step(res, kpool, vpool, remote_k, remote_v, landed,
                                 vict_page, no_evict)
             n_ev = jnp.sum(do & resident).astype(F32)
             vslot = jnp.where(do, sets * w_ways + vways, n)
-            kpool = paged_scatter(kpool, vslot, page_k, mode="drop")
-            vpool = paged_scatter(vpool, vslot, page_v, mode="drop")
+            # a None v (the latent layout) is an empty subtree: skipped
+            pools = jax.tree.map(
+                lambda pool, tier: paged_scatter(
+                    pool, vslot,
+                    paged_gather(tier, jnp.maximum(pids, 0)).astype(
+                        pool.dtype), mode="drop"), pools, tiers)
             res = residency.insert(res, sets, vways, pids, now=clock,
                                    ready=clock, dirty=False, gate=do)
-            return (res, kpool, vpool), evicted, n_ev
+            return (res, pools), evicted, n_ev
 
-        (res, kpool, vpool), evicted, n_ev = jax.lax.cond(
+        (res, (kpool, vpool)), evicted, n_ev = jax.lax.cond(
             jnp.any(landed), do_land,
             lambda args: (args, no_evict, jnp.zeros((), F32)),
-            (res, kpool, vpool))
+            (res, (kpool, vpool)))
 
         present, set_idx, way, ready_ok = residency.lookup(res, needed,
                                                            clock)
         local_hit = present & ready_ok
         slot = set_idx * w_ways + way
-        k_local = paged_gather(kpool, jnp.maximum(slot, 0))
-        v_local = paged_gather(vpool, jnp.maximum(slot, 0))
+        k_local, v_local = jax.tree.map(
+            lambda pool: paged_gather(pool, jnp.maximum(slot, 0)),
+            (kpool, vpool))
         res = residency.touch(res, set_idx, way, clock, pol,
                               gate=local_hit)
         res = residency.mark_dirty(res, set_idx, way, writes,

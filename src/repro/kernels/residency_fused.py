@@ -34,7 +34,10 @@ tests/test_chip_compile.py):
   * DMA'd rows must be whole (sublane, 128-lane) tiles: a payload row
     that is not (e.g. the (4, 1, 8) rows of the hot-path benchmark) is
     flattened and zero-padded to one outside the kernel (`_dma_view`),
-    at the cost of a relayout copy of the pools per call.
+    at the cost of a relayout copy of the pools per call. A latent
+    store holds its pages as whole tiles already (a 64-token page of
+    576 values is (288, 128)), so its pools, tier and rows move as they
+    are.
 Victim ordering is stable-rank arithmetic (O(W) passes over the table
 per step — the kernel targets set-associative geometries with modest W,
 e.g. 256x16). The pure-jnp oracle is `ref.fused_residency_step`;
@@ -68,17 +71,23 @@ def _pick(vec, iota, i):
 
 
 def _make_kernel(s_sets: int, w_ways: int, p_inflight: int, k_land: int,
-                 n_req: int):
+                 n_req: int, n_t: int):
+    """The kernel over `n_t` page tensors: 2 for a K/V pair, 1 for a
+    latent page. Each tensor brings a pool and a remote tier (inputs)
+    and its pool and per-request rows (outputs)."""
 
-    def kernel(fpar_ref, ipar_ref, page_ref, age_ref, ready_ref,
-               dirty_ref, rrpv_ref, landed_ref, lpage_ref, need_ref,
-               write_ref, kpool_ref, vpool_ref, rk_ref, rv_ref,
-               opage_ref, oage_ref, oready_ref, odirty_ref, orrpv_ref,
-               oevict_ref, onev_ref, ohit_ref,
-               okpool_ref, ovpool_ref, klocal_ref, vlocal_ref,
-               s_landed, s_lpage, s_need, s_write, s_pid, s_land,
-               s_vslot, s_slot, sems):
-        del kpool_ref, vpool_ref  # aliased: read/write via okpool/ovpool
+    def kernel(*refs):
+        (fpar_ref, ipar_ref, page_ref, age_ref, ready_ref, dirty_ref,
+         rrpv_ref, landed_ref, lpage_ref, need_ref, write_ref) = refs[:11]
+        refs = refs[11:]
+        # refs[:n_t] are the pools, aliased: read/write via opool_refs
+        tier_refs, refs = refs[n_t:2 * n_t], refs[2 * n_t:]
+        (opage_ref, oage_ref, oready_ref, odirty_ref, orrpv_ref,
+         oevict_ref, onev_ref, ohit_ref) = refs[:8]
+        refs = refs[8:]
+        opool_refs, local_refs = refs[:n_t], refs[n_t:2 * n_t]
+        (s_landed, s_lpage, s_need, s_write, s_pid, s_land, s_vslot,
+         s_slot, sems) = refs[2 * n_t:]
         b = pl.program_id(0)
         clock = fpar_ref[0, 0]
         dpen = fpar_ref[0, 1]              # dirty_penalty
@@ -219,13 +228,13 @@ def _make_kernel(s_sets: int, w_ways: int, p_inflight: int, k_land: int,
                                         jnp.zeros((1, n_req), I32))
 
         # ---- data movement: landed pages remote -> pool (victim slots)
-        def move(src_k, dst_k, src_v, dst_v):
-            ck = pltpu.make_async_copy(src_k, dst_k, sems.at[0])
-            cv = pltpu.make_async_copy(src_v, dst_v, sems.at[1])
-            ck.start()
-            cv.start()
-            ck.wait()
-            cv.wait()
+        def move(srcs, dsts):
+            copies = [pltpu.make_async_copy(src, dst, sems.at[i])
+                      for i, (src, dst) in enumerate(zip(srcs, dsts))]
+            for c in copies:
+                c.start()
+            for c in copies:
+                c.wait()
 
         def land_body(j, carry):
             src = s_land[j]
@@ -233,8 +242,8 @@ def _make_kernel(s_sets: int, w_ways: int, p_inflight: int, k_land: int,
             @pl.when(src >= 0)
             def _():
                 slot = s_vslot[j]
-                move(rk_ref.at[src], okpool_ref.at[b, slot],
-                     rv_ref.at[src], ovpool_ref.at[b, slot])
+                move([t.at[src] for t in tier_refs],
+                     [o.at[b, slot] for o in opool_refs])
 
             return carry
 
@@ -243,8 +252,8 @@ def _make_kernel(s_sets: int, w_ways: int, p_inflight: int, k_land: int,
         # ---- hit-path gather: pool (post-landing) -> per-request output
         def gather_body(r, carry):
             slot = s_slot[r]
-            move(okpool_ref.at[b, slot], klocal_ref.at[b, r],
-                 ovpool_ref.at[b, slot], vlocal_ref.at[b, r])
+            move([o.at[b, slot] for o in opool_refs],
+                 [o.at[b, r] for o in local_refs])
             return carry
 
         jax.lax.fori_loop(0, n_req, gather_body, 0)
@@ -292,6 +301,8 @@ def fused_residency_step(res, kpool, vpool, remote_k, remote_v, landed,
     `ref.fused_residency_step` (same signature + `interpret`, same
     returns). Pools and the remote tier must share a dtype (the landing
     DMA is a raw copy; the jnp chain's astype is a no-op there anyway).
+    A latent store passes `vpool = remote_v = None` and gets `None` back
+    for its v pool and v rows: the kernel then moves one tensor.
     """
     pol = residency.as_policy(pol)
     b, s_sets, w_ways = res.page.shape
@@ -299,13 +310,17 @@ def fused_residency_step(res, kpool, vpool, remote_k, remote_v, landed,
     p_inflight = int(landed.shape[1])
     n_req = int(needed_pages.shape[1])
     k_land = min(p_inflight, n_slots)
-    assert remote_k.dtype == kpool.dtype and remote_v.dtype == vpool.dtype
+    assert (vpool is None) == (remote_v is None)
+    pools = [p for p in (kpool, vpool) if p is not None]
+    tiers = [t for t in (remote_k, remote_v) if t is not None]
+    assert all(t.dtype == p.dtype for t, p in zip(tiers, pools))
+    n_t = len(pools)
 
-    kpool_v, undo_pool = _dma_view(kpool, 2)
-    vpool_v, _ = _dma_view(vpool, 2)
-    rk_v, _ = _dma_view(remote_k, 1)
-    rv_v, _ = _dma_view(remote_v, 1)
-    row_v = tuple(kpool_v.shape[2:])
+    views = [_dma_view(p, 2) for p in pools]
+    undo_pool = views[0][1]
+    pool_vs = [v for v, _ in views]
+    tier_vs = [_dma_view(t, 1)[0] for t in tiers]
+    row_v = tuple(pool_vs[0].shape[2:])
 
     fpar = jnp.stack([jnp.asarray(clock, F32),
                       jnp.asarray(pol.dirty_penalty, F32)]).reshape(1, 2)
@@ -319,23 +334,21 @@ def fused_residency_step(res, kpool, vpool, remote_k, remote_v, landed,
     tbl = lambda dt: jax.ShapeDtypeStruct((b, s_sets, w_ways), dt)
 
     outs = pl.pallas_call(
-        _make_kernel(s_sets, w_ways, p_inflight, k_land, n_req),
+        _make_kernel(s_sets, w_ways, p_inflight, k_land, n_req, n_t),
         grid=(b,),
         in_specs=[smem, smem, meta, meta, meta, meta, meta,
                   vec(p_inflight), vec(p_inflight), vec(n_req),
-                  vec(n_req), any_spec, any_spec, any_spec, any_spec],
+                  vec(n_req)] + [any_spec] * (2 * n_t),
         out_specs=[meta, meta, meta, meta, meta, vec(k_land), vec(1),
-                   vec(n_req), any_spec, any_spec, any_spec, any_spec],
+                   vec(n_req)] + [any_spec] * (2 * n_t),
         out_shape=[
             tbl(I32), tbl(F32), tbl(F32), tbl(I32), tbl(F32),
             jax.ShapeDtypeStruct((b, 1, k_land), I32),         # evicted
             jax.ShapeDtypeStruct((b, 1, 1), F32),              # n_evict
             jax.ShapeDtypeStruct((b, 1, n_req), I32),          # local_hit
-            jax.ShapeDtypeStruct(kpool_v.shape, kpool.dtype),
-            jax.ShapeDtypeStruct(vpool_v.shape, vpool.dtype),
-            jax.ShapeDtypeStruct((b, n_req) + row_v, kpool.dtype),
-            jax.ShapeDtypeStruct((b, n_req) + row_v, vpool.dtype),
-        ],
+        ] + [jax.ShapeDtypeStruct(v.shape, v.dtype) for v in pool_vs]
+        + [jax.ShapeDtypeStruct((b, n_req) + row_v, v.dtype)
+           for v in pool_vs],
         scratch_shapes=[
             pltpu.SMEM((p_inflight,), I32),    # landed flag per slot
             pltpu.SMEM((p_inflight,), I32),    # landed page per slot
@@ -345,20 +358,23 @@ def fused_residency_step(res, kpool, vpool, remote_k, remote_v, landed,
             pltpu.SMEM((k_land,), I32),        # lane's DMA source, -1 off
             pltpu.SMEM((k_land,), I32),        # lane's victim slot
             pltpu.SMEM((n_req,), I32),         # request's probe slot
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((n_t,)),
         ],
-        input_output_aliases={11: 8, 12: 9},
+        input_output_aliases={11 + i: 8 + i for i in range(n_t)},
         interpret=interpret,
         name=KERNEL_NAME,
     )(fpar, ipar, res.page, res.age, res.ready,
       res.dirty.astype(I32), res.rrpv,
       as_vec(landed), as_vec(landed_pages), as_vec(needed_pages),
-      as_vec(needed_writes), kpool_v, vpool_v, rk_v, rv_v)
+      as_vec(needed_writes), *pool_vs, *tier_vs)
 
-    (opage, oage, oready, odirty, orrpv, evicted, n_ev, hit, okpool,
-     ovpool, k_local, v_local) = outs
+    opage, oage, oready, odirty, orrpv, evicted, n_ev, hit = outs[:8]
+    opools = [undo_pool(p) for p in outs[8:8 + n_t]]
+    locals_ = [undo_pool(x) for x in outs[8 + n_t:]]
+    if n_t == 1:
+        opools.append(None)
+        locals_.append(None)
     res2 = residency.ResidencyState(page=opage, age=oage, ready=oready,
                                     dirty=odirty > 0, rrpv=orrpv)
-    return (res2, undo_pool(okpool), undo_pool(ovpool), evicted[:, 0],
-            n_ev[:, 0, 0], undo_pool(k_local), undo_pool(v_local),
+    return (res2, *opools, evicted[:, 0], n_ev[:, 0, 0], *locals_,
             hit[:, 0] > 0)

@@ -15,6 +15,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -88,3 +89,52 @@ def test_paged_gather_compiles_at_remote_fetch_shape(one_chip, n_remote,
     compiled = jax.jit(PG.paged_gather).lower(
         s((n_remote,) + row, BF16), s((n_idx,), I32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the latent store's cell (bench `store-dsv3-fullctx-b8`): 8 tenants x 4
+# layers = 32 stores, 48-slot (3 x 16) pools, every one of 256 pages
+# requested a step, 256 remote pages a store, 64-token pages of 576
+# values held as (288, 128) tiles
+LATENT = dict(stores=32, slots=48, ways=16, r=256, n_remote=32 * 256,
+              page_tokens=64, latent_dim=576)
+
+
+def test_latent_store_step_compiles_without_relayout(one_chip,
+                                                     monkeypatch):
+    """The latent store's whole step compiles for a v5e with both
+    kernels, and no op copies, pads or transposes a page-sized array:
+    the pools, the tier and the served rows move only through the
+    kernels, the remote-fetch branch and the hit/miss select."""
+    import re
+
+    from repro.core import daemon_store
+    from repro.core.fabric import FabricConfig
+    from repro.kernels import ops
+    # compile the TPU path here: the described chip is not the backend
+    monkeypatch.setattr(ops, "resolve_impl", lambda impl="auto": "pallas")
+    cfg = daemon_store.KVStoreConfig(
+        num_local_pages=LATENT["slots"], page_tokens=LATENT["page_tokens"],
+        latent_dim=LATENT["latent_dim"], pool_ways=LATENT["ways"],
+        fabric=FabricConfig(num_modules=4), page_budget_per_step=36287)
+    s = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    state = jax.tree.map(s, jax.eval_shape(
+        lambda: daemon_store.init_kv_store_batch(cfg, LATENT["stores"])))
+    tier = jax.ShapeDtypeStruct((LATENT["n_remote"],) + cfg.page_row(),
+                                BF16, sharding=one_chip)
+    need = jax.ShapeDtypeStruct((LATENT["stores"], LATENT["r"]), I32,
+                                sharding=one_chip)
+    wr = jax.ShapeDtypeStruct(need.shape, jnp.bool_, sharding=one_chip)
+    # the state is donated, as a decode loop does: the kernel then
+    # writes the pool in place
+    text = jax.jit(lambda st, t, p, w: daemon_store.step_fetch_batch(
+        st, cfg, t, None, p, None, w), donate_argnums=0).lower(
+            state, tier, need, wr).compile().as_text()
+    assert RF.in_compiled(text)
+    assert re.search(r"%paged_gather[.\d]* = .*tpu_custom_call", text)
+    page = LATENT["page_tokens"] * LATENT["latent_dim"]
+    moved = re.compile(r"= bf16\[([\d,]+)\]\S* ([a-z-]+)\(")
+    ops_seen = {m.group(2) for m in map(moved.search, text.splitlines())
+                if m and np.prod([int(d) for d in m.group(1).split(",")])
+                >= page}
+    assert not ops_seen & {"copy", "copy-start", "pad", "transpose",
+                           "concatenate", "dynamic-update-slice"}, ops_seen

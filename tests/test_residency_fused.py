@@ -2,7 +2,6 @@
 fused-vs-chain store bit-identity, compile counts, BENCH schema."""
 import json
 import re
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -92,6 +91,37 @@ def test_fused_kernel_matches_oracle(seed, pol_name):
     args = _rand_case(seed)
     _assert_same(R.fused_residency_step(*args, pol),
                  RF.fused_residency_step(*args, pol, interpret=True))
+
+
+@pytest.mark.parametrize("pol_name", POLICY_NAMES)
+def test_fused_kernel_matches_oracle_latent(pol_name):
+    """The latent layout (one page tensor: a 64-token page of 576 values
+    held as (288, 128) tiles, `vpool = remote_v = None`): the kernel
+    moves one pool and equals the oracle on every output, and both give
+    None for the v pool and the v rows."""
+    pol = residency.as_policy(pol_name)
+    (res, kpool, _, rk, _, landed, lp, needed, writes,
+     clock) = _rand_case(3, row=(288, 128))
+    args = (res, kpool.astype(jnp.bfloat16), None,
+            rk.astype(jnp.bfloat16), None, landed, lp, needed, writes,
+            clock)
+    oracle = R.fused_residency_step(*args, pol)
+    kernel = RF.fused_residency_step(*args, pol, interpret=True)
+    assert oracle[2] is None and oracle[6] is None
+    assert kernel[2] is None and kernel[6] is None
+    _assert_same(oracle, kernel)
+    assert bool(jnp.any(oracle[7])) and bool(jnp.any(landed))
+
+
+def test_dma_view_moves_latent_rows_as_they_are():
+    """A latent page held as (288, 128) bfloat16 tiles takes the no-copy
+    branch of `_dma_view` (pools, tier and rows move without a relayout);
+    a K/V row that is not whole tiles still gets the padded view."""
+    pool = jnp.zeros((2, 12, 288, 128), jnp.bfloat16)
+    view, undo = RF._dma_view(pool, 2)
+    assert view is pool and undo(view) is view
+    odd = jnp.zeros((2, 12, 4, 1, 8), jnp.bfloat16)
+    assert RF._dma_view(odd, 2)[0].shape[-1] == 128
 
 
 @pytest.mark.parametrize("pol_name", POLICY_NAMES)
@@ -213,13 +243,55 @@ def test_store_phase_scopes_in_compiled_step(impl):
         assert re.search(rf"[/(]{re.escape(scope)}[/)]", op_names), scope
 
 
-def test_checked_in_bench_jsons_match_producer_schema():
-    """Every committed BENCH_*.json must carry only keys its producer
-    still writes — a stale artifact (old keys) fails here instead of a
-    reader trusting a dead column (benchmarks.validate.BENCH_SCHEMAS)."""
-    from benchmarks.validate import assert_bench_schema
-    root = Path(__file__).resolve().parent.parent
-    found = sorted(root.glob("BENCH_*.json"))
-    assert found, "no BENCH_*.json checked in at repo root"
-    for path in found:
-        assert_bench_schema(path.name, json.loads(path.read_text()))
+def _tiny_producer(name, monkeypatch):
+    """The BENCH_*.json producer `name` at a size a test run holds: the
+    same code paths, shorter traces and fewer steps (each sweep's own
+    `r`/`steps` knobs) and the fewest sweep points its headline reads."""
+    from functools import partial
+
+    from benchmarks import capacity, robustness, scaling, serving
+    if name == "serve":
+        monkeypatch.setattr(serving, "SWEEP", (
+            ("daemon", True, 4, "interleave"),
+            ("remote-style", False, 4, "interleave")))
+        monkeypatch.setattr(serving, "KERNEL_BATCH", 2)
+        monkeypatch.setattr(serving, "KERNEL_POOL_PAGES", 32)
+        monkeypatch.setattr(serving, "KERNEL_PAGES_PER_TENANT", 64)
+        monkeypatch.setattr(serving, "kernel_sweep",
+                            partial(serving.kernel_sweep, steps=4))
+        return partial(serving.serve_sweep, quick=True, steps=6)
+    if name == "robust":
+        monkeypatch.setattr(robustness, "desim_sweep",
+                            partial(robustness.desim_sweep, r=300))
+        monkeypatch.setattr(robustness, "store_sweep",
+                            partial(robustness.store_sweep, steps=8))
+        return partial(robustness.robust_sweep, quick=True)
+    if name == "scale":
+        monkeypatch.setattr(scaling, "C_SWEEP", (1, 2))
+        monkeypatch.setattr(scaling, "MODULE_SWEEP", (4,))
+        monkeypatch.setattr(scaling, "desim_scaling",
+                            partial(scaling.desim_scaling, r=300))
+        monkeypatch.setattr(scaling, "store_scaling",
+                            partial(scaling.store_scaling, steps=8))
+        return partial(scaling.scale_sweep, quick=True)
+    monkeypatch.setattr(capacity, "FRACS", (0.05, 0.10, 0.20))
+    monkeypatch.setattr(capacity, "POLICY_NAMES", ("lru", "fifo"))
+    monkeypatch.setattr(capacity, "desim_capacity",
+                        partial(capacity.desim_capacity, r=300))
+    monkeypatch.setattr(capacity, "store_capacity",
+                        partial(capacity.store_capacity, steps=8))
+    return partial(capacity.capacity_sweep, quick=True)
+
+
+@pytest.mark.parametrize("name", ["serve", "robust", "scale", "capacity"])
+def test_checked_in_bench_jsons_match_producer_schema(name, monkeypatch):
+    """What each BENCH_*.json producer writes today carries only keys
+    `benchmarks.validate.BENCH_SCHEMAS` allows, and every key the schema
+    names at the top: the producer runs here, in-process and small, so a
+    schema that drifts from its producer fails on a clean tree (the
+    artifacts themselves are build outputs, not checked in)."""
+    from benchmarks.validate import BENCH_SCHEMAS, assert_bench_schema
+    doc = _tiny_producer(name, monkeypatch)()
+    fname = f"BENCH_{name}.json"
+    assert_bench_schema(fname, json.loads(json.dumps(doc)))
+    assert set(doc) == BENCH_SCHEMAS[fname]["top"] - {"mesh"}, fname
