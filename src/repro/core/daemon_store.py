@@ -307,7 +307,8 @@ class ReplicatedKVStoreState(NamedTuple):
 STAT_KEYS = ("sub_block_fetches", "page_moves", "wire_bytes",
              "uncompressed_bytes", "local_hits", "requests", "stall_steps",
              "writeback_bytes", "dirty_evicts", "evictions",
-             "writeback_offers", "landings", "remote_rows")
+             "writeback_offers", "landings", "remote_rows",
+             "request_iterations")
 
 # per-decode-step series channels the telemetry ring samples (the
 # post-schedule fabric/stats view of the sequence's step)
@@ -665,6 +666,41 @@ def _offer_evictions(wb_one, carry, evicted):
             n_offers)
 
 
+def _request_order(cfg: KVStoreConfig, needed_pages, local_hit):
+    """The requests `_schedule` visits, in order: (order, n, gap).
+
+    A hit schedules nothing, has no stall and — under a static ratio —
+    writes back the ratio it read; its one effect is its service call's
+    decay of the fabric's offered-demand EMAs (`fabric.serve_dual_at`,
+    read only by the adaptive controller). So where `cfg.adaptive_ratio`
+    is off only the misses are visited: `order` (R+1,) holds their
+    indices first, in request order (rank from `cumsum`, a scatter with
+    `mode="drop"`, no sort), padded with R; `n` is their count; `gap`
+    (R+1, M) int32 counts, by module, the hits before each visited miss
+    since the previous one, and row `n` the hits after the last — the
+    decays the skipped service calls owed. Under an adaptive ratio a hit
+    moves the ratio, so every request is visited: (arange(R), R, None).
+    """
+    r = needed_pages.shape[0]
+    if cfg.adaptive_ratio:
+        return jnp.arange(r), r, None
+    miss = ~local_hit
+    n = jnp.sum(miss)
+    rank = jnp.where(miss, jnp.cumsum(miss) - 1, r + 1)
+    order = jnp.full((r + 1,), r, jnp.int32).at[rank].set(
+        jnp.arange(r, dtype=jnp.int32), mode="drop")
+    m = cfg.fabric.num_modules
+    hit_on = local_hit[:, None] & (
+        fabric.place(cfg.fabric, needed_pages)[:, None] == jnp.arange(m))
+    # hits before each request, by module (row R: all of them)
+    before = jnp.cumsum(
+        jnp.concatenate([jnp.zeros((1, m), bool), hit_on]), axis=0,
+        dtype=jnp.int32)
+    seen = before[order]
+    gap = seen - jnp.concatenate([jnp.zeros((1, m), jnp.int32), seen[:-1]])
+    return order, n, gap
+
+
 def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
               needed_pages, needed_offsets, local_hit, clock, evicted,
               nic=None, cu=None, active=True):
@@ -695,6 +731,13 @@ def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
     the victim page's module writeback channel. Only the valid entries
     are visited (`_offer_evictions`); the `writeback_offers` stat counts
     them.
+
+    The request loop visits the misses only where the ratio is static
+    (`_request_order`): a hit there changes nothing but the fabric's
+    offered-demand EMAs, which decay by `(1 - EMA_ALPHA) ** hits` in
+    closed form at each visited miss and after the last; every other
+    leaf is the one a scan over all R requests gives. The
+    `request_iterations` stat counts the visits.
 
     `nic`/`cu`/`active` switch on the compute plane's two-leg pricing:
     when a per-replica NIC bank is passed, every transfer (requests AND
@@ -780,12 +823,40 @@ def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
         stall = jnp.where(miss, jnp.maximum(served_at - clock, 0.0), 0.0)
         return (eng, fab, nic), (do_line, do_page, stall)
 
-    with jax.named_scope("store.request_scan"):
-        (eng, fab, nic), (line_sent, scheduled, stalls) = jax.lax.scan(
-            sched_one, (eng, fab, nic), jnp.arange(r))
+    def decay(carry, k):
+        """The EMA decay that the hits of `gap` row k owed: by module on
+        the fabric; on unit `cu`'s NIC once a hit, whatever its module."""
+        eng, fab, nic = carry
+        fab = fab._replace(line_rate=fab.line_rate * fade[k],
+                           page_rate=fab.page_rate * fade[k])
+        if nic is not None:
+            f = fade_nic[k]
+            nic = nic._replace(line_rate=nic.line_rate.at[cu].multiply(f),
+                               page_rate=nic.page_rate.at[cu].multiply(f))
+        return eng, fab, nic
 
-    n_sub = jnp.sum(line_sent)
-    n_sched = jnp.sum(scheduled)
+    def visit(i, c):
+        carry, n_sub, n_sched, stalls = c
+        if gap is not None:
+            carry = decay(carry, i)
+        j = order[i]
+        carry, (do_line, do_page, stall) = sched_one(carry, j)
+        return (carry, n_sub + do_line, n_sched + do_page,
+                stalls.at[j].set(stall))
+
+    with jax.named_scope("store.request_scan"):
+        order, n_visit, gap = _request_order(cfg, needed_pages, local_hit)
+        if gap is not None:
+            keep = jnp.float32(1.0 - fabric.EMA_ALPHA)
+            fade = keep ** gap.astype(F32)
+            fade_nic = keep ** jnp.sum(gap, axis=1).astype(F32)
+        zero = jnp.zeros((), jnp.int32)
+        (eng, fab, nic), n_sub, n_sched, stalls = jax.lax.fori_loop(
+            0, n_visit, visit,
+            ((eng, fab, nic), zero, zero, jnp.zeros((r,), F32)))
+        if gap is not None:
+            eng, fab, nic = decay((eng, fab, nic), n_visit)
+
     sub_bytes = n_sub * line_wire
     stt = seq.stats
     stats = {
@@ -806,6 +877,7 @@ def _schedule(seq: SeqState, fab: FabricState, cfg: KVStoreConfig,
         "landings": stt["landings"],
         "remote_rows": stt["remote_rows"],
         "writeback_offers": stt["writeback_offers"] + n_offers,
+        "request_iterations": stt["request_iterations"] + n_visit,
     }
 
     # ---- telemetry plane (DESIGN.md §10): recorded HERE, at the oracle
